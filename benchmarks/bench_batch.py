@@ -13,12 +13,14 @@ and derived records queries/sec plus the batch-vs-per-query speedup.
 The acceptance bar for the batch engine is ≥5× the per-query device loop
 at batch size 64 on CPU-interpret.
 
-``ServingEngine`` scaling: the second phase measures queries/sec through
+``ServingEngine`` scaling: a second phase measures queries/sec through
 the full serving frontend at 1 vs N simulated host devices.  The device
 count is baked into the process at jax init, so each configuration runs
-in a subprocess with ``--xla_force_host_platform_device_count`` set
-(``--serving`` puts this module in worker mode: run the serving bench
-in-process, print one JSON record).  Results land in
+in a subprocess with ``--xla_force_host_platform_device_count`` set on
+the CPU backend (``--serving`` puts this module in worker mode: run the
+serving bench in-process, print one JSON record).  It runs before the
+in-process phase: a chip belongs to one process at a time, so the parent
+stays off JAX until its workers are done.  Results land in
 ``BENCH_serving.json``: q/s, the paper's pages/candidates per query,
 kNN rounds + host syncs per batch (the plan/execute acceptance
 metrics), and — for the ``paged-prefetch`` config — the async
@@ -456,13 +458,22 @@ def bench_serving_scaling(configs=SERVING_CONFIGS,
     code).  ``real_io`` (the --real-io flag) drops the OS page cache
     before each cold store pass so pages/query reflects device IO."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # one process per chip: this parent never initializes a JAX backend
+    # (a child could not open a chip the parent holds), so a probe child
+    # reports which backend the workers will see
+    backend = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, check=True).stdout.split()[-1]
     results = {}
     for label, nd, extra_env in configs:
         env = dict(os.environ)
-        flags = [f for f in env.get("XLA_FLAGS", "").split()
-                 if "host_platform_device_count" not in f]
-        flags.append(f"--xla_force_host_platform_device_count={nd}")
-        env["XLA_FLAGS"] = " ".join(flags)
+        if backend == "cpu":
+            # simulated host devices exist only on the CPU backend; an
+            # accelerator host runs every config on its real devices
+            flags = [f for f in env.get("XLA_FLAGS", "").split()
+                     if "host_platform_device_count" not in f]
+            flags.append(f"--xla_force_host_platform_device_count={nd}")
+            env["XLA_FLAGS"] = " ".join(flags)
         env["REPRO_STORAGE"] = ""
         env["REPRO_PREFETCH"] = ""
         env["REPRO_INTERPRET"] = ""
@@ -540,8 +551,10 @@ if __name__ == "__main__":
         print(json.dumps(serving_worker()))
     else:
         print("name,us_per_call,derived")
-        main()
-        # only the full phase rewrites the committed BENCH_serving.json —
-        # a BENCH_QUICK sanity run must not clobber it with 1-rep numbers
+        # the per-config workers run first, while this process is still
+        # off JAX; only the full phase rewrites the committed
+        # BENCH_serving.json — a BENCH_QUICK sanity run must not clobber
+        # it with 1-rep numbers
         if not QUICK:
             bench_serving_scaling(real_io="--real-io" in sys.argv)
+        main()

@@ -108,7 +108,7 @@ def _stage_thunks(sh):
     return [
         ("pdist", lambda: ops.pdist(q, p)),
         ("rankeval", lambda: ops.rankeval(x, coef, lo, hi, n)[0]),
-        ("range_filter", lambda: ops.range_filter(q, p, r)[0]),
+        ("range_filter", lambda: ops.range_filter(q, p, r)),
     ]
 
 

@@ -58,4 +58,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.env import use_compile_cache
+    use_compile_cache()
     main()

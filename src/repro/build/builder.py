@@ -29,7 +29,6 @@ from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..core.clustering import Clustering
 from ..core.metrics import MetricSpace
@@ -132,7 +131,7 @@ def device_build(space: MetricSpace, n_clusters: int, m: int = 3,
     """Run the device build pipeline and return its structural output.
 
     ``exact_sweeps`` runs the clustering / pivot argmax sweeps in f64
-    (scoped ``enable_x64``) for structural bit-parity with the host
+    (scoped ``jax.enable_x64``) for structural bit-parity with the host
     build; f32 sweeps are available for accelerators without fast f64
     and only risk picking different (equally valid) centers/pivots.
     """
@@ -155,7 +154,7 @@ def device_build(space: MetricSpace, n_clusters: int, m: int = 3,
     member_idx, mask, counts, _ = cluster_major(clustering.members)
     X = space.data
     dtype = np.float64 if exact_sweeps else np.float32
-    ctx = enable_x64() if exact_sweeps else nullcontext()
+    ctx = jax.enable_x64(True) if exact_sweeps else nullcontext()
     with ctx:
         rows_sw = jnp.asarray(X[member_idx].astype(dtype))
         mask_dev = jnp.asarray(mask)
@@ -256,7 +255,7 @@ def retrain_device(sub: MetricSpace, cent_row: np.ndarray, m: int,
     d1_np = np.zeros((1, n_pad), np.float64)
     d1_np[0, :n] = d1
     dtype = np.float64 if exact_sweeps else np.float32
-    ctx = enable_x64() if exact_sweeps else nullcontext()
+    ctx = jax.enable_x64(True) if exact_sweeps else nullcontext()
     with ctx:
         piv_gids = np.asarray(fft_sweeps(
             jnp.asarray(rows_np.astype(dtype)), jnp.asarray(mask_np),

@@ -9,7 +9,7 @@ argmax sweeps on device; ``device_kmeans`` runs Lloyd iterations with
 Structural parity with the host build: the sweeps use the *direct*
 (diff) distance formulation — the same math as the host's
 ``dist_one_to_many`` — and by default run in f64 via the scoped
-``jax.experimental.enable_x64`` context, so every argmax sees values
+``jax.enable_x64`` context, so every argmax sees values
 within ~1 ulp of the host's and picks the same centers except on exact
 ties.  ``exact_sweeps=False`` drops to f32 for accelerators without
 fast f64; the resulting index is still exact (any partition is — the
@@ -29,7 +29,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..core.clustering import Clustering
 from ..core.metrics import MetricSpace, cdist
@@ -99,7 +98,7 @@ def device_kcenter(space: MetricSpace, k: int, seed: int = 0,
     rng = np.random.default_rng(seed)
     first = int(rng.integers(n))
     dtype = np.float64 if exact_sweeps else np.float32
-    ctx = enable_x64() if exact_sweeps else nullcontext()
+    ctx = jax.enable_x64(True) if exact_sweeps else nullcontext()
     with ctx:
         X = jnp.asarray(space.data.astype(dtype))
         centers, assign, _ = _kcenter_sweeps(

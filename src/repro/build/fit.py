@@ -35,6 +35,9 @@ import jax
 import jax.numpy as jnp
 
 _E_SLACK = 2.0      # rint half-steps + f32 eval slop (mirrors snapshot)
+# full-f32 contractions: the solve and the certified error estimate
+# assume f32 products, not the TPU's default bf16 operand rounding
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def cheb_basis(t: jax.Array, degree: int) -> jax.Array:
@@ -84,8 +87,8 @@ def _fit_kernel(cols: jax.Array, counts: jax.Array, deg_req: jax.Array,
 
     T = cheb_basis(t, max_degree)                                 # (G,n,C)
     Tw = T * w[:, :, None] * cmask[:, None, :]
-    A = jnp.einsum("gnc,gnd->gcd", Tw, Tw)
-    b = jnp.einsum("gnc,gn->gc", Tw, ranks)
+    A = jnp.einsum("gnc,gnd->gcd", Tw, Tw, precision=_HIGHEST)
+    b = jnp.einsum("gnc,gn->gc", Tw, ranks, precision=_HIGHEST)
     # identity rows pin masked coefficients to 0; live rows get a
     # scale-aware jitter (diag(A) ≈ n/2 per Chebyshev coefficient)
     jitter = 1e-6 * jnp.maximum(n, 1.0)
@@ -108,7 +111,8 @@ def _fit_kernel(cols: jax.Array, counts: jax.Array, deg_req: jax.Array,
 
     # device-side certified error estimate (§3): deployed-polynomial
     # deviation at the data points + derivative bound × largest t-gap
-    pred = jnp.clip(jnp.rint(jnp.einsum("gnc,gc->gn", T, coef)),
+    pred = jnp.clip(jnp.rint(jnp.einsum("gnc,gc->gn", T, coef,
+                                        precision=_HIGHEST)),
                     0.0, jnp.maximum(n - 1.0, 0.0)[:, None])
     err_pt = jnp.max(jnp.abs(pred - ranks) * w, axis=1)
     deriv = jnp.sum((c_idx.astype(jnp.float32) ** 2)[None, :]

@@ -62,7 +62,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .. import env
 from ..kernels import ops
@@ -315,7 +314,7 @@ class _ResidentBackend:
             if bucket > slots.size:
                 sub = jnp.pad(sub, ((0, bucket - slots.size), (0, 0)),
                               constant_values=_FAR)
-            ball, _ = ops.range_filter(
+            ball = ops.range_filter(
                 plan.qf, sub, rf * (1.0 + _R_REL) + _BALL_ABS + eps)
             ball = np.asarray(ball, bool)[:, :slots.size]
             ex._count_sync()
@@ -452,7 +451,7 @@ class _PagedBackend:
         hits = np.zeros_like(cand)
         if len(io.slots):
             rows64 = store.gather(io.slots)
-            ball, _ = ops.range_filter(
+            ball = ops.range_filter(
                 plan.qf, jnp.asarray(_pad_bucket(rows64.astype(np.float32))),
                 rf * (1.0 + _R_REL) + _BALL_ABS)
             ball = np.asarray(ball, bool)[:, :len(io.slots)]
@@ -647,6 +646,15 @@ class QueryExecutor:
         """(B, P) bool — error-widened ring box ∧ TriPrune ∧ validity."""
         return self._plan_arrays(qf, rf)[0]
 
+    def _seed_dists(self, qf: jax.Array) -> np.ndarray:
+        """(B, K, m) host array of query→pivot L2 distances — the kNN
+        plan's seed (pivots are data rows, so the nearest live one bounds
+        every query's k-th ball from a known row)."""
+        s = self.snap
+        K, _, m = s.rids.shape
+        d2 = ops.pdist(qf, s.pivots.reshape(K * m, s.d))
+        return np.asarray(jnp.sqrt(jnp.maximum(d2, 0.0))).reshape(-1, K, m)
+
     def _ball_filter(self, qf: jax.Array, rf: jax.Array) -> jax.Array:
         """(B, P) bool — fused L2-ball prefilter over the snapshot's
         *filter plane*: the reduced-precision row copy when
@@ -656,7 +664,7 @@ class QueryExecutor:
         filter — otherwise."""
         s = self.snap
         frows, eps = s.filter_rows()
-        ball, _ = ops.range_filter(qf, frows.reshape(s.n_slots, s.d),
+        ball = ops.range_filter(qf, frows.reshape(s.n_slots, s.d),
                                    rf * (1.0 + _R_REL) + _BALL_ABS + eps)
         return ball.astype(bool)
 
@@ -984,6 +992,11 @@ class ShardedExecutor(QueryExecutor):
         if self.n_shards <= 1:
             super().__init__(snapshot, prefetch=prefetch)
             return
+        # the unpadded single-device pivot plane (K·m·d floats) for the
+        # kNN seed: a Pallas kernel cannot take operands sharded over
+        # the mesh outside shard_map, and the 1-device shapes keep the
+        # seed bit-identical to the unsharded executor's
+        self._pivots = snapshot.pivots
         K_pad = -(-snapshot.K // self.n_shards) * self.n_shards
         snapshot = snapshot.pad_clusters(K_pad)
         # cluster-major arrays shard on axis 0 (logical axis "clusters");
@@ -1016,6 +1029,17 @@ class ShardedExecutor(QueryExecutor):
         if self.n_shards <= 1 or self.snap.store is not None:
             return super()._ball_filter(qf, rf)
         return self._ball_fn(qf, rf, *self._dev_arrays)
+
+    def _seed_dists(self, qf):
+        if self.n_shards <= 1:
+            return super()._seed_dists(qf)
+        K, m, d = self._pivots.shape
+        dq = np.asarray(jnp.sqrt(jnp.maximum(
+            ops.pdist(qf, self._pivots.reshape(K * m, d)), 0.0)))
+        # padding clusters hold no live slot: the planner masks them
+        return np.pad(dq.reshape(-1, K, m),
+                      ((0, 0), (0, self.snap.K - K), (0, 0)),
+                      constant_values=np.inf)
 
     # NOTE: no _sq_dists override — the full (B, P) distance matrix is
     # only ever needed by the single-device loop's eager seeding; the
@@ -1062,20 +1086,20 @@ def _sharded_pipeline(mesh: Mesh, axis: str, n_rings: int, specs: tuple):
         snap = _local_view(arrays)
         # the ops wrappers trace with shard-local shapes here, so their
         # tile policy sizes blocks to the per-device slice automatically
-        ball, _ = ops.range_filter(
+        ball = ops.range_filter(
             qf, snap.rows.reshape(-1, snap.rows.shape[-1]),
             rf * (1.0 + _R_REL) + _BALL_ABS)
         return ball.astype(bool)
 
     out_sharded = P(None, axis)
     return (
-        jax.jit(shard_map(plan_body, mesh=mesh,
-                          in_specs=(rep, rep) + specs,
-                          out_specs=(out_sharded, out_sharded),
-                          check_rep=False)),
-        jax.jit(shard_map(ball_body, mesh=mesh,
-                          in_specs=(rep, rep) + specs,
-                          out_specs=out_sharded, check_rep=False)),
+        jax.jit(jax.shard_map(plan_body, mesh=mesh,
+                              in_specs=(rep, rep) + specs,
+                              out_specs=(out_sharded, out_sharded),
+                              check_vma=False)),
+        jax.jit(jax.shard_map(ball_body, mesh=mesh,
+                              in_specs=(rep, rep) + specs,
+                              out_specs=out_sharded, check_vma=False)),
     )
 
 
@@ -1127,9 +1151,10 @@ def _sharded_knn_loop(mesh: Mesh, axis: str, n_rings: int, specs: tuple,
                 jnp.sum(candb, axis=1), axis),
             kth_select=merged_kth)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(rep, rep) + specs,
-                             out_specs=(P(None, axis), P()),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(rep, rep) + specs,
+                                 out_specs=(P(None, axis), P()),
+                                 check_vma=False))
 
 
 def make_executor(snapshot: LIMSSnapshot, *, sharded: bool | None = None,

@@ -314,13 +314,12 @@ class Planner:
             s = self.ex.snap
             qf = jnp.asarray(Q64, jnp.float32)
             K, n_max, m = s.rids.shape
-            dq = np.asarray(jnp.sqrt(jnp.maximum(
-                ops.pdist(qf, s.pivots.reshape(K * m, s.d)), 0.0)))
+            dq = self.ex._seed_dists(qf)                        # (B, K, m)
             self.ex._count_sync()
             live_k = s.valid_np.reshape(K, n_max).any(axis=1)       # (K,)
-            dqm = np.where(np.repeat(live_k, m)[None], dq, np.inf)
-            r0 = dqm.min(axis=1).astype(np.float64) * (1.0 + _SEED_REL) \
-                + _BALL_ABS
+            dqm = np.where(live_k[None, :, None], dq, np.inf)
+            r0 = dqm.reshape(dq.shape[0], K * m).min(axis=1).astype(
+                np.float64) * (1.0 + _SEED_REL) + _BALL_ABS
             plan = CandidatePlan(
                 kind="knn", B=Q64.shape[0], k=int(k_eff),
                 max_rounds=int(max_rounds), growth=2.0, radii=r0,

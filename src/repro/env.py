@@ -141,6 +141,30 @@ def get(name: str) -> str:
     return knob.default if val == "" else val
 
 
+# fixed in-checkout cache path: JAX keys cache entries on it, so it must
+# not move between runs (never a temp name, pid or time stamp)
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (``chip_smoke.py``, ``benchmarks/run.py``, the
+    examples) call this once at start-up; importing the package never
+    does.  A set ``JAX_COMPILATION_CACHE_DIR`` is already read by JAX
+    itself and wins — nothing is set in code then.  Otherwise the cache
+    lives in ``.jax_cache`` at the root of the checkout.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR
+
+
 def describe() -> str:
     """Human-readable table of all knobs (used by ``python -m repro.env``)."""
     lines = []

@@ -68,7 +68,7 @@ _TILE_NAMES = {
 }
 # tile axes that address the 128-wide lane dimension on TPU/GPU
 _LANE_TILES = {"pdist": ("bp",), "range_filter": ("bp",),
-               "rankeval": ("bb",), "pdist_rankeval": ("bb",)}
+               "rankeval": ("bb",), "pdist_rankeval": ("bg", "bb")}
 
 
 def mode() -> str:

@@ -10,9 +10,12 @@ Math is shared with the staged kernels (``xla._gram_sq`` mirrors
 function), so fused-vs-staged bit-identity within a lane is structural,
 not coincidental — and pinned by tests.
 
-Grid: (B//bb, G//bg); each cell loads a (bb, d) query tile and a (bg, d)
-pivot tile plus the (bg,)-shaped model params, and writes a (bb, bg)
-distance tile and two (bg, bb) rank tiles.
+Grid: (B//bb, G//bg); each cell loads a (bb, d) query tile, a (bg, d)
+pivot tile, a (1, bb) row of guard radii and (bg, 1) columns of model
+params, and writes a (bb, bg) distance tile and two (bg, bb) rank
+tiles.  Every block is 2-D with its last dim the full array dim or a
+multiple of the 128-lane tile, so on TPU both ``bb`` and ``bg`` are
+lane-aligned (``ops.py`` pads G to the group tile).
 """
 from __future__ import annotations
 
@@ -34,12 +37,13 @@ def _pdist_rankeval_kernel(q_ref, piv_ref, rg_ref, coef_ref, lo_ref,
     qn = jnp.sum(qb * qb, axis=-1, keepdims=True)
     pn = jnp.sum(pv * pv, axis=-1, keepdims=True)
     g = jax.lax.dot_general(qb, pv, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     d2 = jnp.maximum(qn + pn.T - 2.0 * g, 0.0)
     dq = jnp.sqrt(d2)                                   # (bb, bg)
-    rg = rg_ref[...].astype(jnp.float32)                # (bb,)
-    xlo = dq.T - rg[None, :]                            # (bg, bb)
-    xhi = dq.T + rg[None, :]
+    rg = rg_ref[...].astype(jnp.float32)                # (1, bb)
+    xlo = dq.T - rg                                     # (bg, bb)
+    xhi = dq.T + rg
     coef = coef_ref[...]
     lo = lo_ref[...]
     hi = hi_ref[...]
@@ -70,6 +74,8 @@ def pdist_rankeval_pallas(q: jax.Array, piv: jax.Array, coef: jax.Array,
     G, n_coef = coef.shape
     assert piv.shape == (G, d) and B % bb == 0 and G % bg == 0, (
         q.shape, piv.shape, bg, bb)
+    lo, hi, n = (a.reshape(G, 1) for a in (lo, hi, n))
+    rg = rg.reshape(1, B)
     kern = functools.partial(_pdist_rankeval_kernel, n_coef=n_coef,
                              n_rings=n_rings)
     return pl.pallas_call(
@@ -78,11 +84,11 @@ def pdist_rankeval_pallas(q: jax.Array, piv: jax.Array, coef: jax.Array,
         in_specs=[
             pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bg, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
+            pl.BlockSpec((1, bb), lambda i, j: (0, i)),
             pl.BlockSpec((bg, n_coef), lambda i, j: (j, 0)),
-            pl.BlockSpec((bg,), lambda i, j: (j,)),
-            pl.BlockSpec((bg,), lambda i, j: (j,)),
-            pl.BlockSpec((bg,), lambda i, j: (j,)),
+            pl.BlockSpec((bg, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bb, bg), lambda i, j: (i, j)),

@@ -116,6 +116,17 @@ def _pad_rows(x: jax.Array, mult: int, fill: float = 0.0) -> jax.Array:
     return pad_to(x, mult, axis=0, fill=fill)
 
 
+def _group_tile(g: int, bg: int | None, mode: str, kernel: str) -> int:
+    """Group-dim tile of the rank kernels.  In the fused kernel's
+    (bb, bg) distance block the groups are the lane dim, so on the
+    pallas lane ``bg`` is 128-aligned there; everywhere else it is a
+    sublane dim."""
+    if kernel == "pdist_rankeval" and mode == "pallas":
+        return _tile(g, 128 if bg is None else bg, _LANE)
+    default = 64 if mode == "interpret" else 8
+    return _tile(g, default if bg is None else bg)
+
+
 def _qp_tiles(nq: int, npts: int, d: int, metric: str, mode: str,
               bq: int | None, bp: int | None, qb: int | None,
               kernel: str, consult: bool = True) -> tuple[int, int, int]:
@@ -172,7 +183,7 @@ def static_tiles(kernel: str, metric: str | None,
         return {"bq": bq, "bp": bp, "qb": qb or bq}
     if kernel in ("rankeval", "pdist_rankeval"):
         g, b = dims["g"], dims["b"]
-        bg = _tile(g, 64 if interp else 8)
+        bg = _group_tile(g, None, mode, kernel)
         bb = _point_block(b, 128, interp) if interp \
             else _tile(b, 128, _mode_lane(mode))
         return {"bg": bg, "bb": bb}
@@ -244,7 +255,7 @@ def rankeval(x, coef, lo, hi, n, n_rings: int = 20,
         if t:
             bg = t["bg"] if bg is None else bg
             bb = t["bb"] if bb is None else bb
-    bg = _tile(g, 64 if interp else 8) if bg is None else _tile(g, bg)
+    bg = _group_tile(g, bg, mode, "rankeval")
     # an explicit bb is respected (not grown) but keeps the backend's
     # lane granularity so an override can never break tile alignment
     if interp:
@@ -270,8 +281,8 @@ def rankeval(x, coef, lo, hi, n, n_rings: int = 20,
 
 def range_filter(q, p, r, bq: int | None = None, bp: int | None = None,
                  qb: int | None = None):
-    """Fused L2-ball membership mask for batched range queries.
-    ``qb`` as in :func:`pdist`."""
+    """Fused L2-ball membership mask (nq, np) uint8 for batched range
+    queries, one radius per query.  ``qb`` as in :func:`pdist`."""
     q = jnp.asarray(q)
     p = jnp.asarray(p)
     r = jnp.asarray(r, jnp.float32)
@@ -284,11 +295,11 @@ def range_filter(q, p, r, bq: int | None = None, bp: int | None = None,
     pp = _pad_rows(p, bp, fill=np.inf)     # padding rows never match
     rp = _pad_rows(r, bq, fill=-1.0)
     if mode == "xla":
-        mask, cnt = range_filter_xla(qp, pp, rp, bq=bq, bp=bp, qb=qb)
+        mask = range_filter_xla(qp, pp, rp, bq=bq, bp=bp, qb=qb)
     else:
-        mask, cnt = range_filter_pallas(qp, pp, rp, bq=bq, bp=bp,
-                                        interpret=mode == "interpret")
-    return mask[:nq, :npts], cnt[:nq]
+        mask = range_filter_pallas(qp, pp, rp, bq=bq, bp=bp,
+                                   interpret=mode == "interpret")
+    return mask[:nq, :npts]
 
 
 def pdist_rankeval(q, piv, coef, lo, hi, n, rg, n_rings: int = 20,
@@ -318,7 +329,7 @@ def pdist_rankeval(q, piv, coef, lo, hi, n, rg, n_rings: int = 20,
         if t:
             bg = t["bg"] if bg is None else bg
             bb = t["bb"] if bb is None else bb
-    bg = _tile(G, 64 if interp else 8) if bg is None else _tile(G, bg)
+    bg = _group_tile(G, bg, mode, "pdist_rankeval")
     bb = _tile(B, 128 if bb is None else bb, _mode_lane(mode))
     gp = (-G) % bg
     qp = _pad_rows(q, bb)

@@ -3,7 +3,9 @@
 TPU mapping of the distance computations LIMS performs everywhere
 (clustering passes, pivot columns, refinement): a 2-D grid over
 (query tiles × point tiles). For L2 the Gram trick turns the inner loop
-into an MXU matmul with fp32 accumulation; L1/Linf run on the VPU with the
+into an MXU matmul with fp32 accumulation at ``Precision.HIGHEST`` (a
+TPU f32 matmul at default precision may round its operands to bf16, an
+error far above the planner's guard bands); L1/Linf run on the VPU with the
 feature dimension resident in VMEM.
 
 Tile sizing: (bq, d) + (bp, d) + (bq, bp) in VMEM. With the default
@@ -29,6 +31,7 @@ def _pdist_l2_kernel(q_ref, p_ref, o_ref):
     pn = jnp.sum(p * p, axis=-1, keepdims=True)           # (bp, 1)
     # MXU: (bq, d) @ (d, bp) with fp32 accumulation
     g = jax.lax.dot_general(q, p, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     o_ref[...] = jnp.maximum(qn + pn.T - 2.0 * g, 0.0)
 

@@ -6,8 +6,10 @@ groups at once, evaluate each group's degree-g Chebyshev rank model on a
 rid = clip(rank // ceil(n/N), 0, N-1) — one VMEM pass, VPU only.
 
 Layout: x (G, B) distances; coef (G, C) low→high Chebyshev coefficients
-(zero-padded to a common C); lo/hi/n (G,) per-group normalization; a
-single pass produces both clipped ranks and ring IDs.
+(zero-padded to a common C); lo/hi/n (G,) per-group normalization, fed
+to the kernel as (G, 1) columns so every block keeps the TPU's 2-D
+(sublane, lane) tiling; a single pass produces both clipped ranks and
+ring IDs.
 
 :func:`rank_math` holds the arithmetic itself so the compiled-XLA lane
 (``xla.py``) and the fused pdist→rankeval kernel (``fused.py``) execute
@@ -30,13 +32,13 @@ def rank_math(x, coef, lo, hi, n, *, n_coef: int, n_rings: int):
     """Clenshaw rank eval + ring id on a (g, b) tile; returns int32 pair.
 
     ``x`` (g, b) f32 distances; ``coef`` (g, C); ``lo``/``hi``/``n``
-    (g,).  Pure jnp — callable from a pallas kernel body (on
+    (g,) or (g, 1).  Pure jnp — callable from a pallas kernel body (on
     materialized refs) and from jitted XLA code alike.
     """
     x = x.astype(jnp.float32)
-    lo = lo.astype(jnp.float32)[:, None]                # (g, 1)
-    hi = hi.astype(jnp.float32)[:, None]
-    n = n.astype(jnp.float32)[:, None]
+    lo = lo.astype(jnp.float32).reshape(-1, 1)          # (g, 1)
+    hi = hi.astype(jnp.float32).reshape(-1, 1)
+    n = n.astype(jnp.float32).reshape(-1, 1)
     t = (x - lo) / jnp.maximum(hi - lo, 1e-30) * 2.0 - 1.0
     t = jnp.clip(t, -1.0, 1.0)
     # Clenshaw recurrence, coefficients high -> low (static unroll over C)
@@ -44,9 +46,9 @@ def rank_math(x, coef, lo, hi, n, *, n_coef: int, n_rings: int):
     b2 = jnp.zeros_like(t)
     t2 = 2.0 * t
     for k in range(n_coef - 1, 0, -1):
-        c_k = coef[:, k].astype(jnp.float32)[:, None]
+        c_k = coef[:, k:k + 1].astype(jnp.float32)
         b1, b2 = c_k + t2 * b1 - b2, b1
-    c0 = coef[:, 0].astype(jnp.float32)[:, None]
+    c0 = coef[:, 0:1].astype(jnp.float32)
     r = c0 + t * b1 - b2
     rank = jnp.clip(jnp.rint(r), 0.0, jnp.maximum(n - 1.0, 0.0))
     width = jnp.ceil(n / float(n_rings))
@@ -76,6 +78,7 @@ def rankeval_pallas(x: jax.Array, coef: jax.Array, lo: jax.Array,
     g, b = x.shape
     g2, n_coef = coef.shape
     assert g == g2 and g % bg == 0 and b % bb == 0, (x.shape, coef.shape, bg, bb)
+    lo, hi, n = (a.reshape(g, 1) for a in (lo, hi, n))
     kern = functools.partial(_rankeval_kernel, n_coef=n_coef,
                              n_rings=n_rings)
     return pl.pallas_call(
@@ -84,9 +87,9 @@ def rankeval_pallas(x: jax.Array, coef: jax.Array, lo: jax.Array,
         in_specs=[
             pl.BlockSpec((bg, bb), lambda i, j: (i, j)),
             pl.BlockSpec((bg, n_coef), lambda i, j: (i, 0)),
-            pl.BlockSpec((bg,), lambda i, j: (i,)),
-            pl.BlockSpec((bg,), lambda i, j: (i,)),
-            pl.BlockSpec((bg,), lambda i, j: (i,)),
+            pl.BlockSpec((bg, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bg, bb), lambda i, j: (i, j)),

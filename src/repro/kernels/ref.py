@@ -54,16 +54,6 @@ def rankeval_ref(x: jax.Array, coef: jax.Array, lo: jax.Array,
     return rank.astype(jnp.int32), rid.astype(jnp.int32)
 
 
-def range_filter_ref(q: jax.Array, p: jax.Array, r: jax.Array, bp: int = 128):
-    d2 = pdist_ref(q, p, "sql2")
-    hit = d2 <= (r * r).astype(jnp.float32)[:, None]
-    nq, npts = hit.shape
-    pad = (-npts) % bp
-    hp = jnp.pad(hit, ((0, 0), (0, pad)))
-    cnt = jnp.sum(hp.reshape(nq, -1, bp), axis=-1).astype(jnp.int32)
-    return hit.astype(jnp.uint8), cnt
-
-
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool = True) -> jax.Array:
     """Dense softmax attention with GQA head mapping; fp32 math."""

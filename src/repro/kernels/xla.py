@@ -54,6 +54,7 @@ def _gram_sq(q: jax.Array, p: jax.Array) -> jax.Array:
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
     pn = jnp.sum(p * p, axis=-1, keepdims=True)
     g = jax.lax.dot_general(q, p, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     return jnp.maximum(qn + pn.T - 2.0 * g, 0.0)
 
@@ -149,9 +150,9 @@ def rankeval_xla(x: jax.Array, coef: jax.Array, lo: jax.Array,
 @functools.partial(jax.jit, static_argnames=("bq", "bp", "qb"))
 def range_filter_xla(q: jax.Array, p: jax.Array, r: jax.Array,
                      bq: int = 128, bp: int = 128, qb: int = 0):
-    """Fused sql2 distance + threshold: (mask (nq, npts) uint8,
-    cnt (nq, npts//bp) int32) — same contract as the pallas kernel
-    (``r`` is the per-query radius, squared here).  Same query-blocked
+    """Fused sql2 distance + threshold: mask (nq, npts) uint8 — same
+    contract as the pallas kernel (``r`` is the per-query radius,
+    squared here).  Same query-blocked
     nest as :func:`pdist_xla`: each point block is loaded once per query
     super-tile and reused across the ``qb``-row sub-blocks."""
     q = q.astype(jnp.float32)
@@ -169,27 +170,23 @@ def range_filter_xla(q: jax.Array, p: jax.Array, r: jax.Array,
         def pblock(pb):
             def sub(a):
                 qsub, r2sub = a
-                hit = _gram_sq(qsub, pb) <= r2sub[:, None]
-                return (hit.astype(jnp.uint8),
-                        jnp.sum(hit, axis=1,
-                                keepdims=True).astype(jnp.int32))
+                return (_gram_sq(qsub, pb) <= r2sub[:, None]).astype(
+                    jnp.uint8)
             if qb <= 0 or qb >= gsz:
                 return sub((qs, r2s))
-            m, c = jax.lax.map(sub, (qs.reshape(gsz // qb, qb, d),
-                                     r2s.reshape(gsz // qb, qb)))
-            return m.reshape(gsz, pb.shape[0]), c.reshape(gsz, 1)
+            m = jax.lax.map(sub, (qs.reshape(gsz // qb, qb, d),
+                                  r2s.reshape(gsz // qb, qb)))
+            return m.reshape(gsz, pb.shape[0])
 
         if bp >= npts:
             return pblock(p)
-        m, c = jax.lax.map(pblock, p.reshape(npts // bp, bp, d))
-        return (jnp.swapaxes(m, 0, 1).reshape(gsz, npts),
-                jnp.swapaxes(c, 0, 1).reshape(gsz, -1))
+        return _map_pblocks(pblock, p, bp)
 
     if bq >= nq:
         return qblock((q, r2))
-    m, c = jax.lax.map(qblock, (q.reshape(nq // bq, bq, d),
-                                r2.reshape(nq // bq, bq)))
-    return m.reshape(nq, npts), c.reshape(nq, -1)
+    m = jax.lax.map(qblock, (q.reshape(nq // bq, bq, d),
+                             r2.reshape(nq // bq, bq)))
+    return m.reshape(nq, npts)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rings", "bg", "bb"))

@@ -1,21 +1,29 @@
 """Hardware profiles for the roofline model.
 
-TPU v5e constants (per chip) plus a measured profile for whatever this
-process is actually running on: :func:`machine_profile` returns the
+Published per-chip peaks live in one table, :data:`DEVICE_PEAKS`, keyed
+by ``jax.Device.device_kind``; :func:`machine_profile` returns the
 ``{"name", "peak_flops", "mem_bw"}`` ceiling the pipeline roofline
-report divides by.  On TPU the datasheet constants are used; on CPU the
-peaks are *calibrated* — a jitted f32 GEMM for peak FLOP/s and a large
-streaming elementwise op for memory bandwidth, best-of several runs,
-cached per process — because there is no one datasheet number for "the
-CI runner's CPU" and an unmeasured ceiling would make every utilization
-figure fiction.
+report divides by.  An accelerator whose kind is not in the table is an
+error, never a default.  On CPU the peaks are *calibrated* — a jitted
+f32 GEMM for peak FLOP/s and a large streaming elementwise op for
+memory bandwidth, best-of several runs, cached per process — because
+there is no one datasheet number for "the CI runner's CPU" and an
+unmeasured ceiling would make every utilization figure fiction.
 """
 from __future__ import annotations
 
 import time
 
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
+# Source: Google Cloud documentation, "TPU v5e" — per chip: 197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"name": "tpu-v5e", "peak_flops": 197e12,
+                    "mem_bw": 819e9},
+}
+
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = _V5E["peak_flops"]    # FLOP/s
+HBM_BW = _V5E["mem_bw"]                 # bytes/s
 ICI_BW = 50e9                 # bytes/s per link (≈ usable per direction)
 DCN_BW = 25e9                 # bytes/s per host, inter-pod (approximate)
 HBM_BYTES = 16 * 2**30        # 16 GiB HBM per chip
@@ -64,14 +72,16 @@ def _calibrate_cpu() -> dict:
 
 
 def machine_profile() -> dict:
-    """The roofline ceiling for this process's default backend."""
+    """The roofline ceiling for this process's first device: published
+    peaks for an accelerator (``KeyError`` for a kind not in
+    :data:`DEVICE_PEAKS`), calibrated peaks for the CPU."""
     import jax
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return {"name": "tpu-v5e", "peak_flops": PEAK_FLOPS_BF16,
-                "mem_bw": HBM_BW}
-    if backend in ("gpu", "cuda", "rocm"):
-        # no shipped datasheet constants for arbitrary GPUs; reuse the
-        # calibration approach (the jitted kernels run on the device)
-        return dict(_calibrate_cpu(), name=f"{backend} (calibrated)")
-    return _calibrate_cpu()
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _calibrate_cpu()
+    try:
+        return dict(DEVICE_PEAKS[dev.device_kind])
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {dev.device_kind!r} "
+            f"({dev.platform}); add them to DEVICE_PEAKS") from None

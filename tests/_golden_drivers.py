@@ -63,7 +63,7 @@ def range_resident(ex, Q, r):
     qf = jnp.asarray(Q, jnp.float32)
     rf = jnp.asarray(r_arr, jnp.float32)
     cand = ex._candidate_mask(qf, rf)
-    ball, _ = ops.range_filter(qf, s.rows.reshape(s.n_slots, s.d),
+    ball = ops.range_filter(qf, s.rows.reshape(s.n_slots, s.d),
                                rf * (1.0 + _R_REL) + _BALL_ABS)
     hit = np.asarray(cand & ball.astype(bool))
     out = []
@@ -91,7 +91,7 @@ def range_store(ex, Q, r):
     hit = np.zeros_like(cand)
     if len(plan.slots):
         rows64 = store.gather(plan.slots)
-        ball, _ = ops.range_filter(
+        ball = ops.range_filter(
             qf, jnp.asarray(_pad_bucket(rows64.astype(np.float32))),
             rf * (1.0 + _R_REL) + _BALL_ABS)
         ball = np.asarray(ball, bool)[:, :len(plan.slots)]

@@ -225,13 +225,10 @@ def test_xla_lane_matches_interpret_rankeval(monkeypatch):
 def test_xla_lane_matches_interpret_range_filter(monkeypatch):
     q, p, r = _operands(seed=3)
     _lane(monkeypatch, "on")
-    m_a, c_a = ops.range_filter(q, p, r)
+    m_a = ops.range_filter(q, p, r)
     _lane(monkeypatch, "off")
-    m_b, c_b = ops.range_filter(q, p, r)
+    m_b = ops.range_filter(q, p, r)
     assert np.array_equal(np.asarray(m_a), np.asarray(m_b))
-    # counts are per point-tile, and the lanes tile differently —
-    # compare the per-query totals
-    assert np.array_equal(np.asarray(c_a).sum(-1), np.asarray(c_b).sum(-1))
 
 
 # -------------------------------------------------- fused vs staged
@@ -303,10 +300,9 @@ def test_tuned_tiles_change_grid_not_values(tune_cache, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE", "force")
     assert autotune.tiles_for("pdist", "sql2", _TINY) is not None
     a = np.asarray(ops.pdist(q, p))
-    m_a, c_a = ops.range_filter(q, p, r)
+    m_a = ops.range_filter(q, p, r)
     monkeypatch.setenv("REPRO_AUTOTUNE", "off")
     b = np.asarray(ops.pdist(q, p))
-    m_b, c_b = ops.range_filter(q, p, r)
+    m_b = ops.range_filter(q, p, r)
     assert np.array_equal(a, b)
     assert np.array_equal(np.asarray(m_a), np.asarray(m_b))
-    assert np.array_equal(np.asarray(c_a).sum(-1), np.asarray(c_b).sum(-1))

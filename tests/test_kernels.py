@@ -114,15 +114,14 @@ def test_range_filter_matches_ref(lane, nq, npts, d):
     q = _rand((nq, d), jnp.float32, 5)
     p = _rand((npts, d), jnp.float32, 6)
     r = jax.random.uniform(KEY, (nq,), minval=1.0, maxval=8.0)
-    mask, cnt = ops.range_filter(q, p, r)
+    mask = ops.range_filter(q, p, r)
     d2 = np.asarray(ref.pdist_ref(q, p, "sql2"))
     r2 = np.asarray(r) ** 2
     inner = d2 <= r2[:, None] - 1e-3
     outer = d2 <= r2[:, None] + 1e-3
     m = np.asarray(mask).astype(bool)
+    assert m.shape == (nq, npts)
     assert (inner <= m).all() and (m <= outer).all()
-    # counts consistent with the mask over full tiles
-    assert int(np.asarray(cnt).sum()) == int(m.sum())
 
 
 # -------------------------------------------------------- flash attention
