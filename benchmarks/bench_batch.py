@@ -74,11 +74,6 @@ SERVING_CONFIGS = tuple(
        # goldens run in the same lane inside the worker, so the bar
        # compares plan/execute vs the PR-4 drivers at compiled speed)
        ("xla-compiled", 1, {"REPRO_INTERPRET": "off"}),
-       # full observability (metrics + spans + Chrome trace ring) under
-       # the same golden no-regression bar as every other config — the
-       # obs-overhead acceptance gate.  The other configs run at the
-       # REPRO_OBS default ("on"), so the bar also covers metrics-on.
-       ("obs-trace", 1, {"REPRO_OBS": "trace"}),
        # continuous health monitoring: the background sampler thread +
        # detectors live (DESIGN.md §12), held to the same golden bar —
        # the monitor must not tax the query path it watches.
@@ -430,15 +425,14 @@ def serving_worker() -> dict:
         }
 
     # what the obs layer saw over the whole worker run: scalar metrics
-    # (counters + gauges; histograms stay out of the committed JSON),
-    # the profile ring depth, and the trace ring depth under trace mode
+    # (counters + gauges; histograms stay out of the committed JSON) and
+    # the profile ring depth
     from repro import obs
     scalars = {k: v for k, v in obs.REGISTRY.snapshot().items()
                if not isinstance(v, dict)}
     rec["obs"] = {"mode": obs.obs_mode(),
                   "metrics": len(obs.REGISTRY),
                   "profiles": len(obs.profiles()),
-                  "trace_events": obs.trace_len(),
                   "counters": scalars}
     if mon is not None:
         rec["obs"]["monitor"] = {"ticks": mon.store.ticks,

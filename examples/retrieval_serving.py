@@ -19,6 +19,7 @@ framework trains.
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python examples/retrieval_serving.py
 """
+import glob
 import os
 import tempfile
 import time
@@ -230,21 +231,23 @@ def main() -> None:
           f"all results exact. OK")
 
     # 9) observability (DESIGN.md §11): everything above was also being
-    # measured.  Under REPRO_OBS=trace every span on the query path —
-    # frontend coalescing, plan construction, kernel execution, page
-    # fetches — lands in a Chrome trace_event ring, every served batch
-    # yields a structured QueryProfile (the paper's per-query costs:
-    # pages, candidates, pruning power, rounds, per-stage latency), and
-    # the registry holds the long-run counters and latency histograms.
+    # measured.  Every span on the query path — frontend coalescing,
+    # plan construction, kernel execution, device→host copies — is a
+    # lims.* annotation in a jax.profiler capture, beside the device's
+    # ops; every served batch yields a structured QueryProfile (the
+    # paper's per-query costs: pages, candidates, pruning power, rounds,
+    # bytes copied, per-stage latency), and the registry holds the
+    # long-run counters and latency histograms.
     from repro import obs
-    obs.configure("trace")
-    cold.knn_query_batch(fresh, 1)          # one traced batch
+    obs.configure("on")
+    trace_dir = os.path.join(spill_dir, "serving-trace")
+    with jax.profiler.trace(trace_dir):
+        cold.knn_query_batch(fresh, 1)      # one captured batch
     prof = cold.executor.last_profile
     assert prof is not None and prof.missing() == [], \
         f"served batch must yield a complete QueryProfile: {prof}"
-    trace_path = os.path.join(spill_dir, "serving.trace.json")
-    n_events = obs.write_chrome_trace(trace_path)
-    assert n_events > 0, "trace mode must record query-path spans"
+    assert glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True), \
+        "the capture must write a profiler trace"
     d = prof.as_dict()
     print(f"observability: {d['kind']} batch of {d['batch']} on "
           f"{d['backend']}/{d['storage']} → profile: "
@@ -252,9 +255,9 @@ def main() -> None:
           f"{d['candidates_per_query']:.0f} candidates/query, "
           f"{d['clusters_per_query']:.1f}/{d['n_clusters']} clusters, "
           f"{d['rounds']} round(s), stages "
-          f"{ {k: round(v, 2) for k, v in d['stages_ms'].items()} } ms; "
-          f"{n_events} trace events -> {trace_path} "
-          f"(load in Perfetto). OK")
+          f"{ {k: round(v, 2) for k, v in d['stages_ms'].items()} } ms, "
+          f"{d['d2h_bytes']} bytes to the host; profiler trace -> "
+          f"{trace_dir}. OK")
 
     # 10) continuous health monitoring (DESIGN.md §12): inject placement
     # drift — pin every cluster's ownership to replica 0 while query

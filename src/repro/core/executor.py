@@ -53,7 +53,6 @@ f64 distances on the host (DESIGN.md §3, §8).
 from __future__ import annotations
 
 import functools
-import threading
 import time
 from types import SimpleNamespace
 
@@ -284,9 +283,7 @@ class _ResidentBackend:
             if slots is not None:
                 return self._range_hits_compact(plan, rf, slots)
         ex.last_compact = None
-        hits = plan.mask_dev & ex._ball_filter(plan.qf, rf)
-        ex._count_sync()
-        return np.asarray(hits)
+        return plan.cost.to_host(plan.mask_dev & ex._ball_filter(plan.qf, rf))
 
     def _range_hits_compact(self, plan: CandidatePlan, rf,
                             slots: np.ndarray) -> np.ndarray:
@@ -316,8 +313,7 @@ class _ResidentBackend:
                               constant_values=_FAR)
             ball = ops.range_filter(
                 plan.qf, sub, rf * (1.0 + _R_REL) + _BALL_ABS + eps)
-            ball = np.asarray(ball, bool)[:, :slots.size]
-            ex._count_sync()
+            ball = np.asarray(plan.cost.to_host(ball), bool)[:, :slots.size]
             hits[:, slots] = cand[:, slots] & ball
         ex.last_compact = {"slots": int(slots.size), "bucket": int(bucket),
                            "n_slots": int(s.n_slots)}
@@ -335,8 +331,7 @@ class _ResidentBackend:
         r0 = jnp.asarray(plan.radii, jnp.float32)
         final, rounds = ex._knn_device_loop(
             plan.qf, r0, plan.k, plan.max_rounds)
-        final, rounds = jax.device_get((final, rounds))
-        ex._count_sync()
+        final, rounds = plan.cost.to_host((final, rounds))
         return np.asarray(final, bool), int(rounds)
 
     def _knn_host_rounds(self, plan: CandidatePlan):
@@ -352,14 +347,14 @@ class _ResidentBackend:
         s = ex.snap
         qf = plan.qf
         k_eff = plan.k
+        cost = plan.cost
         d2, eps = ex._filter_dists(qf)
         kth0 = jnp.sqrt(jnp.maximum(
             -jax.lax.top_k(-d2, k_eff)[0][:, -1], 0.0))
         r0 = jnp.asarray(plan.radii, jnp.float32)
         seed = kth0 * (1.0 + _SEED_REL) + _BALL_ABS
         t0 = jnp.ceil(jnp.log2(jnp.maximum(seed, 1e-30) / r0))
-        r = np.asarray(r0 * jnp.exp2(jnp.maximum(t0, 0.0)))
-        ex._count_sync()
+        r = cost.to_host(r0 * jnp.exp2(jnp.maximum(t0, 0.0)))
         B = plan.B
         done = np.zeros(B, bool)
         final = np.zeros((B, s.n_slots), bool)
@@ -376,12 +371,11 @@ class _ResidentBackend:
                                               jnp.float32(eps))
             kth = jnp.sqrt(jnp.maximum(
                 -jax.lax.top_k(-dm, k_eff)[0][:, -1], 0.0))
-            ok = np.asarray((cnt >= k_eff) &
-                            (kth <= rf * (1.0 - _R_REL) - _BALL_ABS - eps))
-            ex._count_sync()
+            ok = cost.to_host((cnt >= k_eff) &
+                              (kth <= rf * (1.0 - _R_REL) - _BALL_ABS - eps))
             newly = ok & ~done
             if newly.any():
-                final[newly] = np.asarray(candb)[newly]
+                final[newly] = cost.to_host(candb)[newly]
                 done |= newly
             if done.all():
                 break
@@ -454,8 +448,8 @@ class _PagedBackend:
             ball = ops.range_filter(
                 plan.qf, jnp.asarray(_pad_bucket(rows64.astype(np.float32))),
                 rf * (1.0 + _R_REL) + _BALL_ABS)
-            ball = np.asarray(ball, bool)[:, :len(io.slots)]
-            ex._count_sync()
+            ball = np.asarray(plan.cost.to_host(ball),
+                              bool)[:, :len(io.slots)]
             hits[:, io.slots] = cand[:, io.slots] & ball
         store.record_queries(io.pages_per_query, io.cand_per_query)
         ex.last_io = io.summary()
@@ -526,7 +520,7 @@ class _PagedBackend:
             # prefetcher, overlapping the kernel work below
             if pf is not None and t + 1 < plan.max_rounds:
                 spec_r = np.where(done, r, r * 2.0)
-                cand_next = ex.planner.eval_mask(qf, spec_r)
+                cand_next = ex.planner.eval_mask(qf, spec_r, plan.cost)
                 spec = cand_next.copy()
                 spec[done] = False
                 pio = plan_batch(spec, store.layout, per_query=False,
@@ -534,10 +528,9 @@ class _PagedBackend:
                 self._pin(plan, pio.pages)   # speculative pages too
                 ticket = pf.submit(pio.pages)
             if len(new):
-                d2_new = np.asarray(ops.pdist(
+                d2_new = plan.cost.to_host(ops.pdist(
                     qf, jnp.asarray(_pad_bucket(
                         rows64.astype(np.float32)))))[:, :len(new)]
-                ex._count_sync()
                 d2g = np.concatenate([d2g, d2_new], axis=1)
             r32 = np.asarray(r, np.float32)
             thr = (r32 * np.float32(1.0 + _R_REL) +
@@ -563,7 +556,7 @@ class _PagedBackend:
                 break
             r = np.where(done, r, r * 2.0)
             if cand_next is None and t + 1 < plan.max_rounds:
-                cand_next = ex.planner.eval_mask(qf, r)
+                cand_next = ex.planner.eval_mask(qf, r, plan.cost)
         else:
             final[~done] = s.valid_np[None]       # exact fallback: scan
             seen[~done] = s.valid_np[None]
@@ -606,15 +599,13 @@ class QueryExecutor:
         # padded array streamed; last-writer-wins like last_io)
         self.last_compact: dict | None = None
         # {backend, rounds, host_syncs, driver} of the most recent kNN
-        # batch (last-writer-wins under concurrent batches, like last_io)
+        # batch (last-writer-wins under concurrent batches, like last_io;
+        # host_syncs is the batch's own count, from its plan's BatchCost)
         self.last_knn: dict | None = None
         self.last_driver: str | None = None
         # QueryProfile of the most recent batch (None until one runs,
         # or with REPRO_OBS=off; last-writer-wins like last_io/last_knn)
         self.last_profile = None
-        # per-thread sync counter: executors serve lock-free concurrent
-        # query threads, and one batch's count must not absorb another's
-        self._tls = threading.local()
         # host mirrors of the model/ring fields for the observed
         # rank-error health stat; materialized once on first profiled
         # batch (never on the off path), see _health_arrays
@@ -630,12 +621,6 @@ class QueryExecutor:
         ``REPRO_PREFETCH=async``)."""
         return self.backend.prefetcher
 
-    def _count_sync(self) -> None:
-        """One device→host materialization on the query path (the kNN
-        acceptance bar counts these per batch; thread-local, so
-        concurrent batches on a shared executor count independently)."""
-        self._tls.syncs = getattr(self._tls, "syncs", 0) + 1
-
     # ------------------------------------------------------ device stages
     # (the three methods a sharding strategy overrides)
     def _plan_arrays(self, qf: jax.Array, rf: jax.Array):
@@ -646,14 +631,14 @@ class QueryExecutor:
         """(B, P) bool — error-widened ring box ∧ TriPrune ∧ validity."""
         return self._plan_arrays(qf, rf)[0]
 
-    def _seed_dists(self, qf: jax.Array) -> np.ndarray:
-        """(B, K, m) host array of query→pivot L2 distances — the kNN
+    def _seed_dists(self, qf: jax.Array) -> jax.Array:
+        """(B, K, m) device array of query→pivot L2 distances — the kNN
         plan's seed (pivots are data rows, so the nearest live one bounds
         every query's k-th ball from a known row)."""
         s = self.snap
         K, _, m = s.rids.shape
         d2 = ops.pdist(qf, s.pivots.reshape(K * m, s.d))
-        return np.asarray(jnp.sqrt(jnp.maximum(d2, 0.0))).reshape(-1, K, m)
+        return jnp.sqrt(jnp.maximum(d2, 0.0)).reshape(-1, K, m)
 
     def _ball_filter(self, qf: jax.Array, rf: jax.Array) -> jax.Array:
         """(B, P) bool — fused L2-ball prefilter over the snapshot's
@@ -718,39 +703,46 @@ class QueryExecutor:
         """Build and record one batch's :class:`QueryProfile`.
 
         Everything derives from state already on the host — the final
-        candidate mask the backend returned, ``last_io``, the
-        thread-local sync counter — so profiling adds *zero* device
-        syncs (the planner's O(1)-syncs-per-batch contract is pinned by
-        tests and must survive instrumentation).  Candidates here are
-        the certified rows refinement actually scanned; clusters are
-        how many of the K clusters those rows span (TriPrune's pruning
-        power, per query)."""
+        candidate mask the backend returned, ``last_io``, the plan's
+        :class:`~repro.core.planner.BatchCost` — so profiling adds
+        *zero* device syncs (the planner's O(1)-syncs-per-batch contract
+        is pinned by tests and must survive instrumentation).
+        Candidates here are the certified rows refinement actually
+        scanned; clusters are how many of the K clusters those rows
+        span (TriPrune's pruning power, per query).  The time spent
+        here before the record is filed is its ``profile`` stage."""
         if not _obs.enabled():
             return
-        s = self.snap
-        B = plan.B
-        K, n_max, _ = s.rids.shape
-        cand = final.sum(axis=1)
-        clusters = final.reshape(B, K, n_max).any(axis=-1).sum(axis=-1)
-        if self.backend.name == "paged" and self.last_io is not None:
-            pages = int(self.last_io["pages"])
-            ppq = float(np.mean(self.last_io["pages_per_query"]))
-        else:
-            pages, ppq = 0, 0.0
-        prof = QueryProfile(
-            kind=plan.kind, batch=B, k=plan.k,
-            backend=self.backend.name,
-            driver=self.last_driver if plan.kind == "knn" else None,
-            storage="paged" if s.store is not None else "resident",
-            n_shards=int(getattr(self, "n_shards", 1)),
-            rounds=int(rounds),
-            host_syncs=int(getattr(self._tls, "syncs", 0)),
-            pages=pages, pages_per_query=ppq,
-            candidates_per_query=float(cand.mean()),
-            clusters_per_query=float(clusters.mean()),
-            n_clusters=int(K), stages=stages,
-            total_s=time.perf_counter() - t0 + plan.plan_s,
-            rank_err_ratio=self._observed_rank_err(final))
+        tp = time.perf_counter()
+        with span("obs.profile"):
+            s = self.snap
+            B = plan.B
+            K, n_max, _ = s.rids.shape
+            cost = plan.cost
+            stages.update(route=cost.route_s, device_wait=cost.device_wait_s,
+                          d2h=cost.d2h_s)
+            cand = final.sum(axis=1)
+            clusters = final.reshape(B, K, n_max).any(axis=-1).sum(axis=-1)
+            if self.backend.name == "paged" and self.last_io is not None:
+                pages = int(self.last_io["pages"])
+                ppq = float(np.mean(self.last_io["pages_per_query"]))
+            else:
+                pages, ppq = 0, 0.0
+            prof = QueryProfile(
+                kind=plan.kind, batch=B, k=plan.k,
+                backend=self.backend.name,
+                driver=self.last_driver if plan.kind == "knn" else None,
+                storage="paged" if s.store is not None else "resident",
+                n_shards=int(getattr(self, "n_shards", 1)),
+                rounds=int(rounds), host_syncs=cost.syncs,
+                pages=pages, pages_per_query=ppq,
+                candidates_per_query=float(cand.mean()),
+                clusters_per_query=float(clusters.mean()),
+                n_clusters=int(K), stages=stages,
+                total_s=time.perf_counter() - t0 + plan.plan_s,
+                rank_err_ratio=self._observed_rank_err(final),
+                d2h_bytes=cost.d2h_bytes, compiles=cost.compiles)
+            stages["profile"] = time.perf_counter() - tp
         self.last_profile = prof
         record_profile(prof)
 
@@ -854,7 +846,6 @@ class QueryExecutor:
         Q = np.atleast_2d(np.asarray(Q, np.float64))
         B = Q.shape[0]
         r_arr = np.broadcast_to(np.asarray(r, np.float64), (B,))
-        self._tls.syncs = 0
         plan = self.planner.plan_range(Q, r_arr)
         return self.execute_range(Q, plan)
 
@@ -866,26 +857,25 @@ class QueryExecutor:
         their f32 device copy; exact refinement needs f64)."""
         s = self.snap
         Q = np.atleast_2d(np.asarray(Q, np.float64))
-        if plan._planner is not self.planner:
-            self._tls.syncs = 0
         t0 = time.perf_counter()
         stages = {"plan": plan.plan_s}
         try:
-            with span("executor.range_execute",
-                      {"B": plan.B, "backend": self.backend.name}):
-                hit = self.backend.range_hits(plan)
-            t1 = time.perf_counter()
-            stages["execute"] = t1 - t0
-            out = []
-            with span("executor.refine", {"B": plan.B}):
-                for b in range(Q.shape[0]):
-                    idx = np.nonzero(hit[b])[0]
-                    ids = s.gids_np[idx]
-                    d_true = dist_one_to_many(Q[b], self._refine_rows(idx),
-                                              "l2")
-                    keep = d_true <= plan.radii[b]
-                    out.append((ids[keep], d_true[keep]))
-            stages["refine"] = time.perf_counter() - t1
+            with plan.cost.charge():
+                with span("executor.range_execute",
+                          {"B": plan.B, "backend": self.backend.name}):
+                    hit = self.backend.range_hits(plan)
+                t1 = time.perf_counter()
+                stages["execute"] = t1 - t0
+                out = []
+                with span("executor.refine", {"B": plan.B}):
+                    for b in range(Q.shape[0]):
+                        idx = np.nonzero(hit[b])[0]
+                        ids = s.gids_np[idx]
+                        d_true = dist_one_to_many(
+                            Q[b], self._refine_rows(idx), "l2")
+                        keep = d_true <= plan.radii[b]
+                        out.append((ids[keep], d_true[keep]))
+                stages["refine"] = time.perf_counter() - t1
             self._emit_profile(plan, hit, 1, stages, t0)
         finally:
             self.backend.release(plan)
@@ -908,34 +898,32 @@ class QueryExecutor:
         k_eff = min(int(k), s.live)
         if k_eff <= 0:
             return (np.empty((B, 0), np.int64), np.empty((B, 0)))
-        self._tls.syncs = 0
         plan = self.planner.plan_knn(Q, k_eff, max_rounds)
         return self.execute_knn(Q, plan)
 
     def execute_knn(self, Q, plan: CandidatePlan):
         """Execute a prebuilt kNN plan (see :meth:`execute_range`).
-        A plan built by a *different* executor's planner starts a fresh
-        sync count here — the builder's syncs were charged to its own
-        thread-local counter when the plan was constructed."""
+        Syncs, copies and compiles accumulate on the plan's own
+        :class:`~repro.core.planner.BatchCost`, which already holds what
+        planning (and routing) charged — wherever the plan was built."""
         Q = np.atleast_2d(np.asarray(Q, np.float64))
-        if plan._planner is not self.planner:
-            self._tls.syncs = 0
         t0 = time.perf_counter()
         stages = {"plan": plan.plan_s}
         try:
-            with span("executor.knn_execute",
-                      {"B": plan.B, "k": plan.k,
-                       "backend": self.backend.name}):
-                final, rounds = self.backend.knn_candidates(plan)
-            t1 = time.perf_counter()
-            stages["execute"] = t1 - t0
-            self.last_knn = {"backend": self.backend.name, "k": plan.k,
-                             "rounds": rounds,
-                             "host_syncs": self._tls.syncs,
-                             "driver": self.last_driver}
-            with span("executor.refine", {"B": plan.B}):
-                out = self._refine_topk(Q, final, plan.k)
-            stages["refine"] = time.perf_counter() - t1
+            with plan.cost.charge():
+                with span("executor.knn_execute",
+                          {"B": plan.B, "k": plan.k,
+                           "backend": self.backend.name}):
+                    final, rounds = self.backend.knn_candidates(plan)
+                t1 = time.perf_counter()
+                stages["execute"] = t1 - t0
+                self.last_knn = {"backend": self.backend.name, "k": plan.k,
+                                 "rounds": rounds,
+                                 "host_syncs": plan.cost.syncs,
+                                 "driver": self.last_driver}
+                with span("executor.refine", {"B": plan.B}):
+                    out = self._refine_topk(Q, final, plan.k)
+                stages["refine"] = time.perf_counter() - t1
             self._emit_profile(plan, final, rounds, stages, t0)
             return out
         finally:
@@ -1034,12 +1022,12 @@ class ShardedExecutor(QueryExecutor):
         if self.n_shards <= 1:
             return super()._seed_dists(qf)
         K, m, d = self._pivots.shape
-        dq = np.asarray(jnp.sqrt(jnp.maximum(
-            ops.pdist(qf, self._pivots.reshape(K * m, d)), 0.0)))
+        dq = jnp.sqrt(jnp.maximum(
+            ops.pdist(qf, self._pivots.reshape(K * m, d)), 0.0))
         # padding clusters hold no live slot: the planner masks them
-        return np.pad(dq.reshape(-1, K, m),
-                      ((0, 0), (0, self.snap.K - K), (0, 0)),
-                      constant_values=np.inf)
+        return jnp.pad(dq.reshape(-1, K, m),
+                       ((0, 0), (0, self.snap.K - K), (0, 0)),
+                       constant_values=np.inf)
 
     # NOTE: no _sq_dists override — the full (B, P) distance matrix is
     # only ever needed by the single-device loop's eager seeding; the

@@ -40,6 +40,8 @@ bands.
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import time
 from dataclasses import dataclass, field
 
@@ -50,7 +52,7 @@ import jax.numpy as jnp
 
 from ..kernels import ops
 from ..obs import registry as _obs
-from ..obs.trace import span
+from ..obs.trace import span, thread_compiles
 
 # f32 guard bands: rank math and distances run in f64 on the host; the
 # device path inflates radii so rounding can never exclude a true result
@@ -130,6 +132,63 @@ def plan_arrays(qf, rf, snap, n_rings: int, fused: bool | None = None):
     return cand.reshape(B, K * n_max), alive
 
 
+@dataclass
+class BatchCost:
+    """What one query batch spent outside its kernels, charged where it
+    happens and carried by the batch's :class:`CandidatePlan` — so the
+    counts belong to the batch, whichever executor, replica or thread
+    does the work (``QueryProfile`` reads them).
+
+    Every device→host copy on the query path goes through
+    :meth:`to_host`.  ``route_s`` is the router's assignment time and
+    ``compiles`` the backend compiles of the batch's planning, routing
+    and execution, each charged through :meth:`charge`.
+
+    A batch the router splits across replicas charges its planning and
+    routing once, to its first sub-batch; the others start from a fresh
+    record (:meth:`CandidatePlan.subset`), so a sum over a batch's
+    profiles counts each copy once."""
+
+    syncs: int = 0               # device→host materializations
+    d2h_bytes: int = 0           # bytes those copies moved
+    device_wait_s: float = 0.0   # host blocked until the value was ready
+    d2h_s: float = 0.0           # the copies themselves
+    route_s: float = 0.0
+    compiles: int = 0
+
+    @contextlib.contextmanager
+    def charge(self, route: bool = False):
+        """Charge the backend compiles this thread makes inside the
+        block to the batch; with ``route`` also the block's time, as
+        ``route_s``."""
+        t0 = time.perf_counter()
+        c0 = thread_compiles()
+        yield self
+        self.compiles += thread_compiles() - c0
+        if route:
+            self.route_s += time.perf_counter() - t0
+
+    def to_host(self, x):
+        """Host copy of ``x`` (an array or a pytree of arrays): block
+        until it is ready (timed as ``device_wait_s``), copy it (timed
+        as ``d2h_s``, its bytes counted), and count one sync.  With
+        observability off only the sync is counted."""
+        self.syncs += 1
+        if not _obs.enabled():
+            return jax.device_get(x)
+        n = sum(a.nbytes for a in jax.tree_util.tree_leaves(x))
+        with span("executor.d2h", {"bytes": n}):
+            t0 = time.perf_counter()
+            jax.block_until_ready(x)
+            t1 = time.perf_counter()
+            out = jax.device_get(x)
+            t2 = time.perf_counter()
+        self.device_wait_s += t1 - t0
+        self.d2h_s += t2 - t1
+        self.d2h_bytes += n
+        return out
+
+
 @dataclass(eq=False)
 class CandidatePlan:
     """One query batch's certified plan, built once and consumed by
@@ -168,6 +227,9 @@ class CandidatePlan:
     # stage in its QueryProfile (a router subset inherits it: the
     # replica executes a slice of the same single construction)
     plan_s: float = 0.0
+    # transfers, routing and compiles charged to this batch so far (a
+    # router subset: see BatchCost and subset)
+    cost: BatchCost = field(default_factory=BatchCost)
 
     @property
     def qf(self) -> jax.Array:
@@ -199,16 +261,14 @@ class CandidatePlan:
     def mask(self) -> np.ndarray:
         """Host copy of :attr:`mask_dev` (materialized once)."""
         if self._mask_np is None:
-            self._mask_np = np.asarray(self.mask_dev)
-            self._planner.ex._count_sync()
+            self._mask_np = self.cost.to_host(self.mask_dev)
         return self._mask_np
 
     @property
     def routing(self) -> np.ndarray:
         """Host copy of :attr:`routing_dev` (materialized once)."""
         if self._routing_np is None:
-            self._routing_np = np.asarray(self.routing_dev)
-            self._planner.ex._count_sync()
+            self._routing_np = self.cost.to_host(self.routing_dev)
         return self._routing_np
 
     def compact_slots(self) -> np.ndarray | None:
@@ -237,7 +297,7 @@ class CandidatePlan:
         return self._compact[0]
 
     def subset(self, idx: np.ndarray, planner: "Planner | None" = None,
-               device=None) -> "CandidatePlan":
+               device=None, shared: bool = True) -> "CandidatePlan":
         """The plan restricted to queries ``idx`` — what the router
         dispatches to a replica (one plan construction per batch still
         holds: a subset is a view, not a rebuild, and does not bump the
@@ -251,11 +311,16 @@ class CandidatePlan:
         through its own pipeline (same math, its own device), with
         ``device`` placing the sliced queries there first.  ``planner``
         rebinds the subset to the replica executor that will run it.
+        The subset's cost record starts from a copy of this plan's when
+        ``shared`` (it carries the batch's planning and routing) and
+        from a fresh one otherwise.
         """
-        idx = np.asarray(idx, np.int64)
-        qf = self._qf[jnp.asarray(idx)]
-        if device is not None:
-            qf = jax.device_put(qf, device)
+        cost = dataclasses.replace(self.cost) if shared else BatchCost()
+        with cost.charge():
+            idx = np.asarray(idx, np.int64)
+            qf = self._qf[jnp.asarray(idx)]
+            if device is not None:
+                qf = jax.device_put(qf, device)
         return CandidatePlan(
             kind=self.kind, B=len(idx), k=self.k,
             max_rounds=self.max_rounds, growth=self.growth,
@@ -265,7 +330,7 @@ class CandidatePlan:
             _mask_np=None if self._mask_np is None else self._mask_np[idx],
             _routing_np=None if self._routing_np is None
             else self._routing_np[idx],
-            plan_s=self.plan_s)
+            plan_s=self.plan_s, cost=cost)
 
 
 class Planner:
@@ -286,11 +351,14 @@ class Planner:
         """Single-round plan at the queries' own radii."""
         self.built += 1
         t0 = time.perf_counter()
-        with span("planner.plan_range", {"B": int(Q64.shape[0])}):
+        cost = BatchCost()
+        with cost.charge(), span("planner.plan_range",
+                                 {"B": int(Q64.shape[0])}):
             plan = CandidatePlan(
                 kind="range", B=Q64.shape[0], k=None, max_rounds=1,
                 growth=1.0, radii=np.array(r64, np.float64),
-                _planner=self, _qf=jnp.asarray(Q64, jnp.float32))
+                _planner=self, _qf=jnp.asarray(Q64, jnp.float32),
+                cost=cost)
         plan.plan_s = time.perf_counter() - t0
         _obs.count("planner.plans_built")
         return plan
@@ -309,13 +377,13 @@ class Planner:
         """
         self.built += 1
         t0 = time.perf_counter()
-        with span("planner.plan_knn",
-                  {"B": int(Q64.shape[0]), "k": int(k_eff)}):
+        cost = BatchCost()
+        with cost.charge(), span("planner.plan_knn",
+                                 {"B": int(Q64.shape[0]), "k": int(k_eff)}):
             s = self.ex.snap
             qf = jnp.asarray(Q64, jnp.float32)
             K, n_max, m = s.rids.shape
-            dq = self.ex._seed_dists(qf)                        # (B, K, m)
-            self.ex._count_sync()
+            dq = cost.to_host(self.ex._seed_dists(qf))          # (B, K, m)
             live_k = s.valid_np.reshape(K, n_max).any(axis=1)       # (K,)
             dqm = np.where(live_k[None, :, None], dq, np.inf)
             r0 = dqm.reshape(dq.shape[0], K * m).min(axis=1).astype(
@@ -323,20 +391,21 @@ class Planner:
             plan = CandidatePlan(
                 kind="knn", B=Q64.shape[0], k=int(k_eff),
                 max_rounds=int(max_rounds), growth=2.0, radii=r0,
-                _planner=self, _qf=qf)
+                _planner=self, _qf=qf, cost=cost)
         plan.plan_s = time.perf_counter() - t0
         _obs.count("planner.plans_built")
         return plan
 
     # -------------------------------------------------- round evaluation
-    def eval_mask(self, qf: jax.Array, radii: np.ndarray) -> np.ndarray:
+    def eval_mask(self, qf: jax.Array, radii: np.ndarray,
+                  cost: BatchCost) -> np.ndarray:
         """(B, P) host candidate mask at explicit per-query radii — the
         paged backend's per-round schedule evaluation (the resident
-        backend evaluates the same math on device, inside its loop)."""
+        backend evaluates the same math on device, inside its loop);
+        the copy is charged to ``cost``."""
         cand, _ = self.ex._plan_arrays(qf, jnp.asarray(radii, jnp.float32))
-        self.ex._count_sync()
         _obs.count("planner.round_evals")
-        return np.asarray(cand)
+        return cost.to_host(cand)
 
 
-__all__ = ["CandidatePlan", "Planner", "plan_arrays"]
+__all__ = ["BatchCost", "CandidatePlan", "Planner", "plan_arrays"]

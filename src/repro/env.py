@@ -80,17 +80,14 @@ _KNOBS = (
          ("", "0", "1"), "",
          "Benchmarks: drop the OS page cache before cold paged passes."),
     Knob("REPRO_OBS",
-         ("", "off", "on", "trace"), "on",
+         ("", "off", "on"), "on",
          "Observability (repro.obs; DESIGN.md §11): off (zero-cost "
          "disabled path), on (metrics registry + span latency "
-         "histograms + QueryProfiles), trace (additionally record "
-         "Chrome trace_event spans for Perfetto)."),
+         "histograms + QueryProfiles; spans also annotate a "
+         "jax.profiler capture as lims.<span>)."),
     Knob("REPRO_OBS_RESERVOIR", None, "1024",
          "Histogram reservoir capacity (samples kept per histogram; "
          "percentiles are exact up to this many observations)."),
-    Knob("REPRO_OBS_TRACE_CAP", None, "20000",
-         "Trace ring capacity: most recent span events kept in "
-         "REPRO_OBS=trace mode."),
     Knob("REPRO_OBS_PROFILES", None, "256",
          "QueryProfile ring capacity: most recent per-batch serving "
          "profiles kept."),

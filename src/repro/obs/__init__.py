@@ -1,10 +1,11 @@
 """Unified observability for the serving stack (DESIGN.md §11).
 
 One process-wide metrics registry (counters / gauges / bounded-reservoir
-histograms), a span tracer over the query path, per-batch
-``QueryProfile`` records, and exporters (JSON, Prometheus text, Chrome
-trace_event).  Controlled by ``REPRO_OBS=off|on|trace``; the disabled
-path costs one string compare and allocates nothing.
+histograms), spans over the query path that also annotate a
+``jax.profiler`` capture (``lims.<span>``, on the device trace's clock),
+per-batch ``QueryProfile`` records, and exporters (JSON, Prometheus
+text).  Controlled by ``REPRO_OBS=off|on``; the disabled path costs one
+string compare and allocates nothing.
 
     from repro import obs
     with obs.span("my.stage"):
@@ -14,13 +15,11 @@ path costs one string compare and allocates nothing.
 """
 from .registry import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                        MetricsRegistry, configure, count, enabled,
-                       obs_mode, observe, set_gauge, tracing)
-from .trace import (clear_trace, instant, span, trace_events,  # noqa: F401
-                    trace_len)
+                       obs_mode, observe, set_gauge)
+from .trace import instant, span  # noqa: F401
 from .profile import (QueryProfile, clear_profiles,  # noqa: F401
                       last_profile, profiles, record_profile)
-from .export import (chrome_trace, json_snapshot,  # noqa: F401
-                     prometheus_text, write_chrome_trace,
+from .export import (json_snapshot, prometheus_text,  # noqa: F401
                      write_json_snapshot, write_prometheus)
 from .timeseries import Series, SeriesStore, sparkline  # noqa: F401
 from .health import (Detector, HealthFinding,  # noqa: F401
@@ -36,11 +35,10 @@ __all__ = [
     "HeatSkewDetector", "Histogram", "MetricsRegistry", "Monitor",
     "PruningRegressionDetector", "QueryProfile", "RankDriftDetector",
     "Series", "SeriesStore", "SloBurnDetector", "active_monitors",
-    "chrome_trace", "clear_profiles", "clear_trace", "configure",
-    "configure_monitor", "count", "default_detectors", "enabled",
-    "instant", "json_snapshot", "last_profile", "maybe_monitor",
-    "monitor_enabled", "monitor_mode", "obs_mode", "observe", "profiles",
-    "prometheus_text", "record_profile", "set_gauge", "shutdown_monitors",
-    "span", "sparkline", "trace_events", "trace_len", "tracing",
-    "write_chrome_trace", "write_json_snapshot", "write_prometheus",
+    "clear_profiles", "configure", "configure_monitor", "count",
+    "default_detectors", "enabled", "instant", "json_snapshot",
+    "last_profile", "maybe_monitor", "monitor_enabled", "monitor_mode",
+    "obs_mode", "observe", "profiles", "prometheus_text",
+    "record_profile", "set_gauge", "shutdown_monitors", "span",
+    "sparkline", "write_json_snapshot", "write_prometheus",
 ]

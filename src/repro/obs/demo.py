@@ -5,8 +5,8 @@ by each building their own inline state; both now build through
 :func:`demo_state` — one tiny synthetic index + engine + query set —
 and layer their workload on top:
 
-* :func:`run_traffic_demo` — the PR-8 exporter smoke: range/kNN/frontend
-  traffic under full tracing, asserting a complete ``QueryProfile``.
+* :func:`run_traffic_demo` — the exporter smoke: range/kNN/frontend
+  traffic with observability on, asserting a complete ``QueryProfile``.
 * :func:`run_health_demo` — the §12 closed loop, deterministically:
   a 4-replica router over the same snapshot, placement drift injected
   by pinning every cluster's ownership to replica 0, then
@@ -24,7 +24,7 @@ import numpy as np
 from . import profile, registry
 
 
-def demo_state(mode: str = "trace") -> SimpleNamespace:
+def demo_state(mode: str = "on") -> SimpleNamespace:
     """One small index + serving engine + query batch (seeded rng)."""
     from ..core import LIMSIndex, MetricSpace, ServingEngine
 
@@ -38,8 +38,8 @@ def demo_state(mode: str = "trace") -> SimpleNamespace:
 
 
 def run_traffic_demo(st: SimpleNamespace | None = None) -> SimpleNamespace:
-    """Serve a small synthetic workload with full tracing enabled."""
-    st = st if st is not None else demo_state("trace")
+    """Serve a small synthetic workload with observability on."""
+    st = st if st is not None else demo_state()
     st.se.range_query_batch(st.Q, 0.7)
     st.se.knn_query_batch(st.Q, 5)
     with st.se.frontend(max_batch=8, slo_ms=5.0) as fe:
@@ -65,7 +65,7 @@ def run_health_demo(st: SimpleNamespace | None = None, ticks: int = 10):
     from ..serving import MonitorDaemon, PlanRouter, ReplicaSet
     from .monitor import Monitor
 
-    st = st if st is not None else demo_state("trace")
+    st = st if st is not None else demo_state()
     snap = st.se.executor.snap
     replicas = ReplicaSet(snap, n_replicas=4)
     router = PlanRouter(replicas)

@@ -1,12 +1,11 @@
-"""Exporters: registry + profiles + trace + monitor → JSON / Prometheus /
-Chrome.
+"""Exporters: registry + profiles + monitor → JSON / Prometheus.
 
-Three read-only renderings of the same state:
+Two read-only renderings of the same state:
 
 * :func:`json_snapshot` — everything (mode, metrics, recent
-  QueryProfiles, trace depth, and — when a monitor is passed or active —
-  its time series and findings) as one JSON-able dict; the programmatic
-  surface and what ``repro.obs.report --json`` writes.
+  QueryProfiles, and — when a monitor is passed or active — its time
+  series and findings) as one JSON-able dict; the programmatic surface
+  and what ``repro.obs.report --json`` writes.
 * :func:`prometheus_text` — the text exposition format: counters and
   gauges as-is; histograms twice — the original summary family with
   quantile labels plus ``_count``/``_sum``, and a parallel ``<name>_hist``
@@ -15,9 +14,9 @@ Three read-only renderings of the same state:
   computable by a stock Prometheus.  Monitor series additionally render
   as ``lims_monitor_series`` gauges.  Metric names are sanitized
   (dots → underscores) to the Prometheus grammar.
-* :func:`chrome_trace` / :func:`write_chrome_trace` — the span ring as a
-  Chrome Trace Event Format JSON object, loadable in Perfetto or
-  chrome://tracing.
+
+Spans are not exported here: they annotate a ``jax.profiler`` capture
+(``repro.obs.trace``), which holds them beside the device's ops.
 
 Exporters never mutate state and take the same locks the recorders do,
 so they are safe to call from a live serving process.
@@ -28,7 +27,6 @@ import json
 
 from . import profile as _prof
 from . import registry as _reg
-from . import trace as _trace
 
 
 def _active_monitor(monitor):
@@ -50,7 +48,6 @@ def json_snapshot(n_profiles: int = 32, monitor=None) -> dict:
         "mode": _reg.obs_mode(),
         "metrics": _reg.REGISTRY.snapshot(),
         "profiles": [p.as_dict() for p in _prof.profiles(n_profiles)],
-        "trace_events": _trace.trace_len(),
     }
     mon = _active_monitor(monitor)
     if mon is not None:
@@ -135,20 +132,6 @@ def _fmt(v: float) -> str:
     return repr(f)
 
 
-def chrome_trace() -> dict:
-    """The span ring as a Chrome Trace Event Format dict."""
-    return _trace.trace_events()
-
-
-def write_chrome_trace(path: str) -> int:
-    """Write the Perfetto-loadable trace JSON to ``path``; returns the
-    number of events written (excluding thread-name metadata)."""
-    doc = chrome_trace()
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return sum(1 for e in doc["traceEvents"] if e.get("ph") == "X")
-
-
 def write_json_snapshot(path: str, n_profiles: int = 32,
                         monitor=None) -> None:
     with open(path, "w") as f:
@@ -161,5 +144,5 @@ def write_prometheus(path: str, monitor=None) -> None:
         f.write(prometheus_text(monitor=monitor))
 
 
-__all__ = ["chrome_trace", "json_snapshot", "prometheus_text",
-           "write_chrome_trace", "write_json_snapshot", "write_prometheus"]
+__all__ = ["json_snapshot", "prometheus_text", "write_json_snapshot",
+           "write_prometheus"]

@@ -13,10 +13,15 @@ inferred from benchmarks.  Every executed batch therefore yields one
   certified set touches per query (out of K), i.e. how hard TriPrune +
   the ring box actually pruned *this* batch — the signal the
   curse-of-dimensionality results say must be measured per query;
-* **rounds / syncs** — growing-radius rounds and device→host syncs
-  (the plan/execute acceptance metrics, now continuously recorded);
-* **per-stage latency** — plan construction, backend execution, exact
-  refinement, and the total.
+* **rounds / syncs / transfers / compiles** — growing-radius rounds,
+  device→host syncs and the bytes they copied, and backend compiles
+  during the batch, all counted on the batch's own cost record
+  (``repro.core.planner.BatchCost``), never on a thread or executor;
+* **per-stage latency** — plan construction, routing, backend
+  execution, exact refinement, building this record, and the total;
+  ``device_wait`` and ``d2h`` are the batch's host waits for device
+  values and its copies, sums that overlap ``plan``/``route``/
+  ``execute``.
 
 Profiles land in a bounded ring (``REPRO_OBS_PROFILES`` records,
 default 256 — a serving window, not a log) and feed the registry's
@@ -39,9 +44,11 @@ from .registry import _int_knob
 REQUIRED_FIELDS = (
     "kind", "batch", "backend", "storage", "n_shards", "rounds",
     "host_syncs", "pages", "pages_per_query", "candidates_per_query",
-    "clusters_per_query", "n_clusters", "stages", "total_s",
+    "clusters_per_query", "n_clusters", "stages", "total_s", "d2h_bytes",
+    "compiles",
 )
-REQUIRED_STAGES = ("plan", "execute", "refine")
+REQUIRED_STAGES = ("plan", "route", "execute", "refine", "device_wait",
+                   "d2h", "profile")
 
 
 @dataclass
@@ -68,6 +75,8 @@ class QueryProfile:
     # (host-sampled over this batch's certified in-ring candidates; None
     # when the batch had none — optional, NOT in REQUIRED_FIELDS)
     rank_err_ratio: float | None = None
+    d2h_bytes: int = 0           # bytes copied device→host for the batch
+    compiles: int = 0            # backend compiles during the batch
 
     def as_dict(self) -> dict:
         return {
@@ -75,6 +84,7 @@ class QueryProfile:
             "backend": self.backend, "driver": self.driver,
             "storage": self.storage, "n_shards": self.n_shards,
             "rounds": self.rounds, "host_syncs": self.host_syncs,
+            "d2h_bytes": self.d2h_bytes, "compiles": self.compiles,
             "pages": self.pages,
             "pages_per_query": round(self.pages_per_query, 3),
             "candidates_per_query": round(self.candidates_per_query, 2),

@@ -27,11 +27,10 @@ Design constraints, in order:
   memory while its mean and extremes stay exact; percentiles are exact
   until the reservoir overflows and statistically representative after.
 
-Mode resolution: ``REPRO_OBS`` (off | on | trace, default on) is read
-once at import and cached in :data:`_MODE`; tests and embedders flip it
-with :func:`configure`.  ``on`` records metrics and span durations;
-``trace`` additionally appends Chrome ``trace_event`` records
-(``repro.obs.trace``).
+Mode resolution: ``REPRO_OBS`` (off | on, default on) is read once at
+import and cached in :data:`_MODE`; tests and embedders flip it with
+:func:`configure`.  ``on`` records metrics and span durations, and
+annotates spans for a ``jax.profiler`` capture (``repro.obs.trace``).
 """
 from __future__ import annotations
 
@@ -65,7 +64,7 @@ _MODE: str = _resolve_mode()
 
 
 def obs_mode() -> str:
-    """The cached observability mode: 'off' | 'on' | 'trace'."""
+    """The cached observability mode: 'off' | 'on'."""
     return _MODE
 
 
@@ -73,21 +72,18 @@ def enabled() -> bool:
     return _MODE != "off"
 
 
-def tracing() -> bool:
-    return _MODE == "trace"
-
-
 def configure(mode: str | None = None) -> str:
-    """Set the observability mode ('off'|'on'|'trace'), or re-read
-    ``REPRO_OBS`` when ``mode`` is None.  Returns the active mode.
-    Existing metric values are kept — mode only gates *recording*."""
+    """Set the observability mode ('off'|'on'), or re-read ``REPRO_OBS``
+    when ``mode`` is None.  Returns the active mode.  Existing metric
+    values are kept — mode only gates *recording*.  A trace of the
+    program's spans is a ``jax.profiler`` capture, not a mode."""
     global _MODE
     if mode is None:
         _MODE = _resolve_mode()
     else:
         mode = str(mode).strip().lower()
-        if mode not in ("off", "on", "trace"):
-            raise ValueError(f"obs mode must be off|on|trace, got {mode!r}")
+        if mode not in ("off", "on"):
+            raise ValueError(f"obs mode must be off|on, got {mode!r}")
         _MODE = mode
     return _MODE
 
@@ -407,4 +403,4 @@ def set_gauge(name: str, v: float) -> None:
 __all__ = ["Counter", "DEFAULT_BUCKET_BOUNDS", "Gauge", "Histogram",
            "MetricsRegistry", "REGISTRY", "configure", "count",
            "default_reservoir", "enabled", "obs_mode", "observe",
-           "set_gauge", "tracing"]
+           "set_gauge"]

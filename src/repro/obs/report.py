@@ -1,16 +1,19 @@
 """``python -m repro.obs.report`` — export serving telemetry to files.
 
 Renders the process-wide observability state (metrics registry, recent
-``QueryProfile`` records, span trace, monitor series/findings) through
-the exporters:
+``QueryProfile`` records, monitor series/findings) through the
+exporters:
 
     python -m repro.obs.report --demo \\
-        --json obs.json --prom obs.prom --trace obs.trace.json
+        --json obs.json --prom obs.prom --trace obs-trace/
 
 ``--demo`` builds a tiny index and serves range/kNN/frontend traffic
-under ``REPRO_OBS=trace`` (``repro.obs.demo``) — a one-command smoke
-check that every exporter produces well-formed output (CI runs exactly
-this).  ``--health`` renders the index-health report (findings, series
+(``repro.obs.demo``) — a one-command smoke check that every exporter
+produces well-formed output (CI runs exactly this).  ``--trace DIR``
+runs that demo under a ``jax.profiler`` capture written to ``DIR``: the
+program's ``lims.*`` spans and the device's ops on one clock, which is
+what explains an idle chip (TensorBoard's profile plugin reads the
+``.xplane.pb``; Perfetto opens ``perfetto_trace.json.gz``).  ``--health`` renders the index-health report (findings, series
 sparklines, SLO attainment, daemon audit); combined with ``--demo`` it
 first drives the deterministic closed-loop drift demo so there are
 findings to show (the monitor CI leg's smoke).  Without ``--demo`` the
@@ -22,6 +25,7 @@ snapshot prints to stdout.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 
@@ -97,12 +101,11 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs.report",
         description="Export LIMS serving telemetry "
-                    "(JSON / Prometheus / Chrome trace / health report).")
+                    "(JSON / Prometheus / profiler trace / health report).")
     ap.add_argument("--demo", action="store_true",
-                    help="serve a small synthetic workload first "
-                         "(trace mode) so there is telemetry to export; "
-                         "with --health, also drive the closed-loop "
-                         "drift demo")
+                    help="serve a small synthetic workload first so "
+                         "there is telemetry to export; with --health, "
+                         "also drive the closed-loop drift demo")
     ap.add_argument("--health", action="store_true",
                     help="render the index-health report (findings, "
                          "series sparklines, SLO attainment) to stdout")
@@ -110,18 +113,26 @@ def main(argv=None) -> int:
                     help="write the JSON snapshot here")
     ap.add_argument("--prom", metavar="PATH",
                     help="write Prometheus text format here")
-    ap.add_argument("--trace", metavar="PATH",
-                    help="write the Chrome trace_event file here "
-                         "(load in Perfetto / chrome://tracing)")
+    ap.add_argument("--trace", metavar="DIR",
+                    help="capture a jax.profiler trace of the --demo "
+                         "traffic into DIR: the program's lims.* spans "
+                         "and the device's ops on one clock")
     ap.add_argument("--profiles", type=int, default=32, metavar="N",
                     help="recent QueryProfiles to include in the JSON "
                          "snapshot (default 32)")
     args = ap.parse_args(argv)
+    if args.trace and not args.demo:
+        ap.error("--trace captures the demo traffic: pass --demo too")
 
     monitor = daemon = None
     if args.demo:
+        import jax
+
         from . import demo as _demo
-        st = _demo.run_traffic_demo()
+        capture = jax.profiler.trace(args.trace, create_perfetto_trace=True) \
+            if args.trace else contextlib.nullcontext()
+        with capture:
+            st = _demo.run_traffic_demo()
         if args.health:
             _, monitor, daemon = _demo.run_health_demo(st)
     if monitor is None:
@@ -138,8 +149,7 @@ def main(argv=None) -> int:
         export.write_prometheus(args.prom, monitor=monitor)
         wrote.append(f"prometheus text -> {args.prom}")
     if args.trace:
-        n = export.write_chrome_trace(args.trace)
-        wrote.append(f"chrome trace ({n} events) -> {args.trace}")
+        wrote.append(f"profiler trace -> {args.trace}")
 
     if args.health:
         if monitor is None:

@@ -118,6 +118,10 @@ class ServingFrontend:
         self._lat_hist = Histogram("frontend.request_latency_s")
         self._router_obj: PlanRouter | None = None
         self._gen: int | None = None
+        # sequence number of the last dispatched batch (batcher thread
+        # only): the frontend.execute span carries it, so every span of
+        # one batch can be grouped in a profiler capture
+        self._seq = 0
         self._batcher = threading.Thread(
             target=self._batch_loop, daemon=True, name="lims-frontend")
         self._batcher.start()
@@ -220,9 +224,11 @@ class ServingFrontend:
 
     def _execute(self, batch: list) -> None:
         t_run = time.monotonic()
+        self._seq += 1
         try:
             with span("frontend.execute",
-                      {"B": len(batch), "kind": batch[0].kind}):
+                      {"B": len(batch), "kind": batch[0].kind,
+                       "seq": self._seq}):
                 router = self._router()
                 Q = np.stack([r.q for r in batch])
                 if batch[0].kind == "range":

@@ -85,8 +85,10 @@ class PlanRouter:
     def _assign(self, plan) -> np.ndarray:
         """(B,) replica id per query: ownership votes over the plan's
         TriPrune routing, least-loaded tie-break, round-robin for
-        unrouted queries."""
-        with span("router.assign", {"B": plan.B}):
+        unrouted queries.  Its time (the routing copy included) and
+        compiles are charged to the plan's cost record."""
+        with plan.cost.charge(route=True), span("router.assign",
+                                                {"B": plan.B}):
             return self._assign_inner(plan)
 
     def _assign_inner(self, plan) -> np.ndarray:
@@ -135,7 +137,7 @@ class PlanRouter:
                 with span("router.subbatch",
                           {"replica": rep.rid, "B": len(idx)}):
                     sub = plan.subset(idx, planner=rep.ex.planner,
-                                      device=rep.device)
+                                      device=rep.device, shared=g == 0)
                     results[g] = getattr(rep.ex, method)(Q[idx], sub)
                 rep.record(len(idx))
             except BaseException as e:  # re-raised on the caller thread

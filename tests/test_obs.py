@@ -6,14 +6,18 @@ its histograms are bounded reservoirs whose percentiles match numpy
 bit-for-bit below the cap; ``REPRO_OBS=off`` makes every recording
 helper a no-op that allocates nothing (tracemalloc-pinned); every
 served batch — resident, paged, sharded — yields a *complete*
-``QueryProfile``; the exporters emit well-formed Prometheus text and a
-Perfetto-loadable Chrome trace; the frontend's metric memory stays
-bounded under a 10k-request soak (the unbounded-list regression this
-PR removed); and the buffer-pool + prefetch counters sum to total page
-reads (``misses + prefetch_reads == page_reads``).
+``QueryProfile``; a ``jax.profiler`` capture of served batches holds
+the program's ``lims.*`` spans, nested on the batcher's thread; each
+batch's host syncs, device→host bytes and compiles are its own; the
+exporters emit well-formed Prometheus text; the frontend's metric
+memory stays bounded under a 10k-request soak; and the buffer-pool +
+prefetch counters sum to total page reads (``misses + prefetch_reads
+== page_reads``).
 """
+import glob
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -127,13 +131,15 @@ def test_histogram_reservoir_bounded_stats_exact():
 
 def test_mode_gating_and_configure():
     obs.configure("off")
-    assert not _reg.enabled() and not _reg.tracing()
+    assert not _reg.enabled()
     assert span("x") is _NULL               # shared no-op singleton
-    obs.configure("trace")
-    assert _reg.enabled() and _reg.tracing()
+    obs.configure("on")
+    assert _reg.enabled()
     assert span("x") is not _NULL
-    with pytest.raises(ValueError):
-        obs.configure("loud")
+    # a trace is a jax.profiler capture, not a mode
+    for bad in ("loud", "trace"):
+        with pytest.raises(ValueError):
+            obs.configure(bad)
 
 
 def test_off_mode_records_and_allocates_nothing():
@@ -194,18 +200,6 @@ def test_off_mode_records_and_allocates_nothing():
     assert obs_alloc == 0
     assert obs.REGISTRY.counter("offtest.c").value == before
     assert obs.REGISTRY.histogram("offtest.h").count == 1
-
-
-def test_trace_ring_is_bounded(monkeypatch):
-    monkeypatch.setenv("REPRO_OBS_TRACE_CAP", "50")
-    obs.clear_trace()                       # recreate the ring at cap 50
-    obs.configure("trace")
-    for i in range(200):
-        with span("ring.test"):
-            pass
-    assert obs.trace_len() == 50
-    obs.clear_trace()
-    monkeypatch.delenv("REPRO_OBS_TRACE_CAP")
 
 
 # ---------------------------------------------------------------- profiles
@@ -314,32 +308,6 @@ def test_prometheus_text_format():
         assert all(c.isalnum() or c == "_" for c in name)
 
 
-def test_chrome_trace_structure_and_file(tmp_path):
-    obs.configure("trace")
-    obs.clear_trace()
-    with span("trace.outer", {"B": 4}):
-        with span("trace.inner"):
-            pass
-    doc = obs.chrome_trace()
-    assert doc["displayTimeUnit"] == "ms"
-    evs = doc["traceEvents"]
-    meta = [e for e in evs if e["ph"] == "M"]
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert meta and meta[0]["name"] == "thread_name"
-    assert {e["name"] for e in xs} == {"trace.outer", "trace.inner"}
-    for e in xs:
-        assert e["ts"] >= 0 and e["dur"] >= 0 and e["cat"] == "lims"
-    outer = next(e for e in xs if e["name"] == "trace.outer")
-    assert outer["args"] == {"B": 4}
-    # the file a Perfetto load would open: valid JSON, same events
-    path = str(tmp_path / "trace.json")
-    n = obs.write_chrome_trace(path)
-    assert n == 2
-    with open(path) as f:
-        assert json.load(f)["traceEvents"]
-    obs.clear_trace()
-
-
 def test_json_snapshot_round_trips(setup):
     X, ix, snap, path, Q, rs = setup
     obs.configure("on")
@@ -352,12 +320,12 @@ def test_json_snapshot_round_trips(setup):
 
 
 def test_report_demo_smoke(tmp_path):
-    """The packaged reporter end-to-end: demo workload, all three
-    exports, complete profile asserted inside."""
+    """The packaged reporter end-to-end: demo workload under a profiler
+    capture, both exports, complete profile asserted inside."""
     from repro.obs import report
     out_json = str(tmp_path / "obs.json")
     out_prom = str(tmp_path / "obs.prom")
-    out_trace = str(tmp_path / "obs.trace.json")
+    out_trace = str(tmp_path / "obs-trace")
     rc = report.main(["--demo", "--json", out_json, "--prom", out_prom,
                       "--trace", out_trace])
     assert rc == 0
@@ -366,8 +334,11 @@ def test_report_demo_smoke(tmp_path):
     assert doc["profiles"]
     with open(out_prom) as f:
         assert "lims_" in f.read()
-    with open(out_trace) as f:
-        assert json.load(f)["traceEvents"]
+    assert glob.glob(f"{out_trace}/**/*.xplane.pb", recursive=True)
+    assert glob.glob(f"{out_trace}/**/perfetto_trace.json.gz",
+                     recursive=True)
+    with pytest.raises(SystemExit):          # --trace captures the demo
+        report.main(["--trace", out_trace])
 
 
 # ----------------------------------------------------- frontend boundedness
@@ -422,3 +393,180 @@ def test_prefetch_reads_sum_to_page_reads(setup):
     assert s["page_reads"] == s["misses"] + s["prefetch_reads"]
     # and the set actually resident is exactly what was read
     assert s["page_reads"] == len(set(spec) | set(demand))
+
+
+# ----------------------------------------------- per-batch costs, traced
+def _frontend(snap, B):
+    from repro.serving import ServingFrontend
+    return ServingFrontend(QueryExecutor(snap), n_replicas=1, max_batch=B,
+                           slo_ms=1000.0)
+
+
+def _serve_batch(fe, Q, kind, arg):
+    """Dispatch the rows of ``Q`` as exactly one frontend batch: held
+    until every request has queued, then released."""
+    submit = fe.knn_query if kind == "knn" else fe.range_query
+    fe.pause()
+    before = fe.metrics()["submitted"]
+    threads = [threading.Thread(target=submit, args=(q, arg)) for q in Q]
+    for t in threads:
+        t.start()
+    deadline = time.monotonic() + 60.0
+    while fe.metrics()["submitted"] < before + len(Q):
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    fe.resume()
+    for t in threads:
+        t.join(120.0)
+        assert not t.is_alive()
+
+
+_BATCH_SPANS = ("frontend.execute", "router.assign", "planner.plan_knn",
+                "planner.plan_range", "executor.knn_execute",
+                "executor.range_execute", "executor.refine",
+                "executor.d2h", "obs.profile")
+
+
+def test_profiler_capture_holds_program_spans(setup, tmp_path):
+    """Served batches under a jax.profiler capture (CPU): every program
+    span of a batch is a ``lims.*`` event on the batcher thread's line,
+    inside its ``frontend.execute`` (which carries the batch's sequence
+    number), and each kNN execute holds its device→host copy."""
+    import jax
+    X, ix, snap, path, Q, rs = setup
+    obs.configure("on")
+    fe = _frontend(snap, 4)
+    try:
+        _serve_batch(fe, Q[:4], "knn", 3)           # compiles outside
+        _serve_batch(fe, Q[:4], "range", float(rs[0]))
+        with jax.profiler.trace(str(tmp_path)):
+            _serve_batch(fe, Q[4:8], "knn", 3)
+            _serve_batch(fe, Q[4:8], "range", float(rs[0]))
+    finally:
+        fe.close()
+    pb = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[-1]
+    lines = [line for plane in jax.profiler.ProfileData.from_file(pb).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    line = next(ln for ln in lines if any(
+        e.name == "lims.frontend.execute" for e in ln.events))
+    ev = [(e.name[len("lims."):], e.start_ns, e.start_ns + e.duration_ns,
+           dict(e.stats)) for e in line.events
+          if e.name.startswith("lims.")]
+    assert set(_BATCH_SPANS) <= {e[0] for e in ev}
+    outer = [e for e in ev if e[0] == "frontend.execute"]
+    assert [e[3]["seq"] for e in outer] == [3, 4]
+    for name, a, b, _ in ev:
+        assert any(o[1] <= a and b <= o[2] for o in outer), name
+    for _, a, b, _ in [e for e in ev if e[0] == "executor.knn_execute"]:
+        assert any(e[0] == "executor.d2h" and a <= e[1] and e[2] <= b
+                   for e in ev)
+
+
+def test_frontend_host_syncs_same_every_batch(setup, monkeypatch):
+    """Regression: through the frontend, a batch's host syncs are its
+    own — the same count on every one of consecutive equal batches, not
+    a per-thread total that grows batch after batch."""
+    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
+    X, ix, snap, path, Q, rs = setup
+    obs.configure("on")
+    obs.clear_profiles()
+    fe = _frontend(snap, 4)
+    try:
+        for _ in range(6):
+            _serve_batch(fe, Q[:4], "knn", 5)
+    finally:
+        fe.close()
+    syncs = [p.host_syncs for p in obs.profiles()]
+    # the plan's seed distances, the router's routing, the loop's masks
+    assert syncs == [3] * 6
+
+
+@pytest.mark.parametrize("kind,compact", [
+    ("knn", "on"), ("range", "off"), ("range", "on")])
+def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
+    """A routed batch's ``d2h_bytes`` is exactly what its copies hold:
+    kNN — (B, K, m) f32 seed distances, (B, K) bool routing, the
+    (B, n_slots) bool certified mask and the int32 round count; range —
+    routing and the (B, n_slots) bool hits; with compaction on, the
+    host candidate mask it reads comes first, and the (B, bucket) uint8
+    ball replaces the hits unless the union is too large to gather."""
+    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
+    monkeypatch.setenv("REPRO_COMPACT", compact)
+    X, ix, snap, path, Q, rs = setup
+    obs.configure("on")
+    fe = _frontend(snap, 4)
+    try:
+        _serve_batch(fe, Q[:4], kind, 5 if kind == "knn" else float(rs[0]))
+        ex = fe._router_obj.routing_ex
+        p = ex.last_profile
+    finally:
+        fe.close()
+    s = ex.snap
+    B, K, m = 4, s.K, s.m
+    want = B * K + B * s.n_slots
+    if kind == "knn":
+        want += B * K * m * 4 + 4
+    elif compact == "on":
+        lc = ex.last_compact
+        want += B * s.n_slots if lc is None else B * lc["bucket"]
+    assert p.d2h_bytes == want
+    assert p.stages["d2h"] > 0 and p.stages["route"] > 0
+
+
+@pytest.mark.parametrize("kind", ["knn", "range"])
+def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
+    """A batch the router splits across replicas charges its planning
+    and routing copies to one sub-batch: summed over the batch's
+    profiles, ``d2h_bytes`` and ``host_syncs`` are exactly the batch's
+    copies — kNN: the (B, K, m) f32 seed distances and (B, K) bool
+    routing once, then each sub-batch's (b, n_slots) bool mask and
+    int32 round count; range: the routing once, then each sub-batch's
+    (b, n_slots) bool hits."""
+    from repro.serving import ServingFrontend
+    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
+    monkeypatch.setenv("REPRO_COMPACT", "off")
+    X, ix, snap, path, Q, rs = setup
+    obs.configure("on")
+    fe = ServingFrontend(QueryExecutor(snap), n_replicas=4, max_batch=8,
+                         slo_ms=1000.0)
+    try:
+        _serve_batch(fe, Q, kind, 5)             # compiles outside
+        obs.clear_profiles()
+        _serve_batch(fe, Q, kind, 5 if kind == "knn" else float(rs[0]))
+    finally:
+        fe.close()
+    ps = obs.profiles()
+    G = len(ps)
+    assert G > 1                                 # the batch was split
+    assert sum(p.batch for p in ps) == len(Q)
+    B, K, m, n_slots = len(Q), snap.K, snap.m, snap.n_slots
+    if kind == "knn":
+        want = B * K * m * 4 + B * K + B * n_slots + 4 * G
+        syncs = 2 + G
+    else:
+        want = B * K + B * n_slots
+        syncs = 1 + G
+    assert sum(p.d2h_bytes for p in ps) == want
+    assert sum(p.host_syncs for p in ps) == syncs
+    assert sum(p.stages["route"] > 0 for p in ps) == 1
+
+
+def test_compiles_charged_to_the_compiling_batch(setup):
+    """A batch of a new size compiles; that batch's profile counts the
+    compiles and no batch before or after it does."""
+    from repro.data.datasets import gauss_mix
+    X = gauss_mix(613, 7, seed=29)          # shapes no other test serves
+    ix = LIMSIndex(MetricSpace(X, "l2"), n_clusters=3, m=2, n_rings=5)
+    Q = X[:5] + 0.002
+    obs.configure("on")
+    fe = _frontend(LIMSSnapshot.build(ix), 5)
+    try:
+        _serve_batch(fe, Q[:4], "knn", 4)       # first size: compiles
+        obs.clear_profiles()
+        for n in (4, 3, 4, 3):
+            _serve_batch(fe, Q[:n], "knn", 4)
+    finally:
+        fe.close()
+    compiles = [p.compiles for p in obs.profiles()]
+    assert compiles[0] == 0 and compiles[1] > 0
+    assert compiles[2:] == [0, 0]
