@@ -27,6 +27,11 @@ executes it through one of two backends:
     (``REPRO_PREFETCH=async``) that overlaps page IO with kernel
     refinement.
 
+Either backend hands refinement and profiling a batch's candidates as
+per-query slot lists (``CandidateSets``).  A mask computed on the device
+leaves it bit-packed (``_pack_mask``: an eighth of the bool bytes) and is
+decoded on the host without expanding the full slot plane.
+
 ``QueryExecutor`` owns the single-device pipeline; ``ShardedExecutor``
 runs the same plan math cluster-sharded with ``shard_map`` over a mesh
 from ``repro.sharding.logical``: each device holds a contiguous shard of
@@ -241,6 +246,120 @@ def _knn_round_masks(d2, cand, rf, eps):
 
 
 # ---------------------------------------------------------------------------
+# candidate sets to the host: a (B, P) bool mask leaves the device as
+# (B, W) uint32 words and becomes per-query slot lists on the host
+# ---------------------------------------------------------------------------
+_PACK_BITS = 32
+_PACK_LANES = 128
+
+
+def _pack_width(n_slots: int) -> int:
+    """Words per query: P padded to a multiple of 32·128 slots, so W is
+    a multiple of the TPU's 128 lanes."""
+    step = _PACK_BITS * _PACK_LANES
+    return -(-n_slots // step) * _PACK_LANES
+
+
+@jax.jit
+def _pack_mask(mask):
+    """(B, P) bool → (B, W) uint32, bit i of word j = slot i·W + j.
+
+    Strided so that each of the 32 bit planes is a lane-aligned column
+    slice of the mask: the pack is one elementwise pass that reads the
+    mask once, with no relayout.  Padded slots read False."""
+    B, P = mask.shape
+    W = _pack_width(P)
+    words = None
+    for i in range(_PACK_BITS):
+        lo, hi = i * W, min((i + 1) * W, P)
+        if hi <= lo:
+            break
+        plane = mask[:, lo:hi].astype(jnp.uint32)
+        if hi - lo < W:
+            plane = jnp.pad(plane, ((0, 0), (0, W - (hi - lo))))
+        plane = plane << i
+        words = plane if words is None else words | plane
+    return words
+
+
+class CandidateSets:
+    """A batch's candidate slots as per-query lists (CSR): query ``b``'s
+    slots are ``slots[offsets[b]:offsets[b + 1]]``, ascending — the
+    order ``np.nonzero(mask[b])`` gives, which refinement's stable
+    distance sort and the range answers' id order depend on.  The one
+    host form every backend hands refinement and profiling."""
+
+    __slots__ = ("offsets", "slots")
+
+    def __init__(self, offsets: np.ndarray, slots: np.ndarray):
+        self.offsets = offsets      # (B + 1,) int64
+        self.slots = slots          # (nnz,) int64
+
+    def __len__(self) -> int:
+        return len(self.offsets) - 1
+
+    def __getitem__(self, b: int) -> np.ndarray:
+        return self.slots[self.offsets[b]:self.offsets[b + 1]]
+
+    @property
+    def counts(self) -> np.ndarray:
+        """(B,) candidates per query."""
+        return np.diff(self.offsets)
+
+    def clusters(self, n_max: int) -> np.ndarray:
+        """(B,) distinct clusters (``slot // n_max``) per query."""
+        B = len(self)
+        rows = np.repeat(np.arange(B), self.counts)
+        c = self.slots // n_max
+        new = np.ones(c.size, bool)
+        new[1:] = (c[1:] != c[:-1]) | (rows[1:] != rows[:-1])
+        return np.bincount(rows[new], minlength=B)
+
+    def union(self, n_slots: int) -> np.ndarray:
+        """(n_slots,) bool: slots that are a candidate of any query."""
+        u = np.zeros(n_slots, bool)
+        u[self.slots] = True
+        return u
+
+    @classmethod
+    def from_mask(cls, mask: np.ndarray,
+                  cols: np.ndarray | None = None) -> "CandidateSets":
+        """From a (B, n) host bool mask; ``cols`` (ascending) names the
+        slot of each column when the mask covers a gathered subset.
+        Counts ``executor.dense_batches``."""
+        B, n = mask.shape
+        flat = np.flatnonzero(mask)
+        rows, c = np.divmod(flat, max(n, 1))
+        slots = c if cols is None else np.asarray(cols, np.int64)[c]
+        _obs.count("executor.dense_batches")
+        return cls(np.searchsorted(rows, np.arange(B + 1)), slots)
+
+    @classmethod
+    def from_packed(cls, words: np.ndarray) -> "CandidateSets":
+        """From :func:`_pack_mask` words, expanding only the non-zero
+        ones.  Counts ``executor.packed_batches``."""
+        B, W = words.shape
+        flat = words.reshape(-1)
+        nz = np.flatnonzero(flat != 0)
+        bits = np.unpackbits(
+            flat[nz].astype("<u4", copy=False).view(np.uint8),
+            bitorder="little")
+        e = np.flatnonzero(bits.view(bool))
+        i = e & (_PACK_BITS - 1)
+        rows, col = np.divmod(nz[e >> 5], W)
+        # found in (query, word, bit) order; slot i·W + j ascends in
+        # (bit, word), so a stable sort on (query, bit) orders each list
+        key = rows * _PACK_BITS + i
+        if B * _PACK_BITS <= 1 << 16:
+            key = key.astype(np.uint16)      # numpy's radix sort
+        order = np.argsort(key, kind="stable")
+        offsets = np.zeros(B + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=B), out=offsets[1:])
+        _obs.count("executor.packed_batches")
+        return cls(offsets, (i * W + col)[order])
+
+
+# ---------------------------------------------------------------------------
 # execution backends (both consume the same CandidatePlan)
 # ---------------------------------------------------------------------------
 def _knn_driver(ex) -> str:
@@ -275,7 +394,9 @@ class _ResidentBackend:
     def release(self, plan: CandidatePlan) -> None:
         """No storage, nothing pinned."""
 
-    def range_hits(self, plan: CandidatePlan) -> np.ndarray:
+    def range_hits(self, plan: CandidatePlan) -> CandidateSets:
+        """Plan mask ∧ ball filter; over every slot, the hits leave the
+        device bit-packed."""
         ex = self.ex
         rf = jnp.asarray(plan.radii, jnp.float32)
         if compact_enabled() and getattr(ex, "n_shards", 1) <= 1:
@@ -283,10 +404,11 @@ class _ResidentBackend:
             if slots is not None:
                 return self._range_hits_compact(plan, rf, slots)
         ex.last_compact = None
-        return plan.cost.to_host(plan.mask_dev & ex._ball_filter(plan.qf, rf))
+        hits = plan.mask_dev & ex._ball_filter(plan.qf, rf)
+        return CandidateSets.from_packed(plan.cost.to_host(_pack_mask(hits)))
 
     def _range_hits_compact(self, plan: CandidatePlan, rf,
-                            slots: np.ndarray) -> np.ndarray:
+                            slots: np.ndarray) -> CandidateSets:
         """Ball prefilter over the plan's compacted candidate gather
         (DESIGN.md §13): the union candidate rows are gathered from the
         filter plane once into a power-of-two bucket and only the dense
@@ -302,7 +424,7 @@ class _ResidentBackend:
         ex = self.ex
         s = ex.snap
         cand = plan.mask
-        hits = np.zeros_like(cand)
+        hits = np.zeros((plan.B, 0), bool)
         bucket = 0
         if slots.size:
             frows, eps = s.filter_rows()
@@ -314,14 +436,14 @@ class _ResidentBackend:
             ball = ops.range_filter(
                 plan.qf, sub, rf * (1.0 + _R_REL) + _BALL_ABS + eps)
             ball = np.asarray(plan.cost.to_host(ball), bool)[:, :slots.size]
-            hits[:, slots] = cand[:, slots] & ball
+            hits = cand[:, slots] & ball
         ex.last_compact = {"slots": int(slots.size), "bucket": int(bucket),
                            "n_slots": int(s.n_slots)}
         _obs.count("executor.compact_batches")
         if s.n_slots:
             _obs.observe("executor.compact_frac",
                          slots.size / float(s.n_slots))
-        return hits
+        return CandidateSets.from_mask(hits, slots)
 
     def knn_candidates(self, plan: CandidatePlan):
         ex = self.ex
@@ -331,8 +453,8 @@ class _ResidentBackend:
         r0 = jnp.asarray(plan.radii, jnp.float32)
         final, rounds = ex._knn_device_loop(
             plan.qf, r0, plan.k, plan.max_rounds)
-        final, rounds = plan.cost.to_host((final, rounds))
-        return np.asarray(final, bool), int(rounds)
+        words, rounds = plan.cost.to_host((_pack_mask(final), rounds))
+        return CandidateSets.from_packed(words), int(rounds)
 
     def _knn_host_rounds(self, plan: CandidatePlan):
         """The same certified schedule as ``_knn_rounds``, driven from
@@ -383,7 +505,7 @@ class _ResidentBackend:
         else:
             final[~done] = s.valid_np[None]
         ex.last_driver = "rounds"
-        return final, rounds
+        return CandidateSets.from_mask(final), rounds
 
 
 class _PagedBackend:
@@ -426,7 +548,7 @@ class _PagedBackend:
             store.unpin_pages(pages)
 
     # ------------------------------------------------------------- range
-    def range_hits(self, plan: CandidatePlan) -> np.ndarray:
+    def range_hits(self, plan: CandidatePlan) -> CandidateSets:
         """Same candidate mask as the resident path, ball prefilter on
         gathered pages.  Per-pair kernel math is independent of which
         other rows share a launch and the gathered f32 rows are the same
@@ -442,7 +564,7 @@ class _PagedBackend:
         self._pin(plan, io.pages)
         store.fetch(io)
         rf = jnp.asarray(plan.radii, jnp.float32)
-        hits = np.zeros_like(cand)
+        hits = np.zeros((plan.B, 0), bool)
         if len(io.slots):
             rows64 = store.gather(io.slots)
             ball = ops.range_filter(
@@ -450,11 +572,11 @@ class _PagedBackend:
                 rf * (1.0 + _R_REL) + _BALL_ABS)
             ball = np.asarray(plan.cost.to_host(ball),
                               bool)[:, :len(io.slots)]
-            hits[:, io.slots] = cand[:, io.slots] & ball
+            hits = cand[:, io.slots] & ball
         store.record_queries(io.pages_per_query, io.cand_per_query)
         ex.last_io = io.summary()
         ex.last_io["pinned_pages"] = sum(len(p) for p in plan._pins)
-        return hits
+        return CandidateSets.from_mask(hits, io.slots)
 
     # --------------------------------------------------------------- kNN
     def knn_candidates(self, plan: CandidatePlan):
@@ -572,7 +694,7 @@ class _PagedBackend:
                       "pinned_pages": sum(len(p) for p in plan._pins)}
         if pf is not None:
             ex.last_io["prefetch"] = pf.snapshot()
-        return final, rounds
+        return CandidateSets.from_mask(final), rounds
 
 
 # ---------------------------------------------------------------------------
@@ -698,12 +820,12 @@ class QueryExecutor:
             n_rings=self.snap.n_rings, k_eff=k_eff, max_rounds=max_rounds)
 
     # -------------------------------------------------------- observability
-    def _emit_profile(self, plan: CandidatePlan, final: np.ndarray,
+    def _emit_profile(self, plan: CandidatePlan, final: CandidateSets,
                       rounds: int, stages: dict, t0: float) -> None:
         """Build and record one batch's :class:`QueryProfile`.
 
         Everything derives from state already on the host — the final
-        candidate mask the backend returned, ``last_io``, the plan's
+        candidate sets the backend returned, ``last_io``, the plan's
         :class:`~repro.core.planner.BatchCost` — so profiling adds
         *zero* device syncs (the planner's O(1)-syncs-per-batch contract
         is pinned by tests and must survive instrumentation).
@@ -721,8 +843,8 @@ class QueryExecutor:
             cost = plan.cost
             stages.update(route=cost.route_s, device_wait=cost.device_wait_s,
                           d2h=cost.d2h_s)
-            cand = final.sum(axis=1)
-            clusters = final.reshape(B, K, n_max).any(axis=-1).sum(axis=-1)
+            cand = final.counts
+            clusters = final.clusters(n_max)
             if self.backend.name == "paged" and self.last_io is not None:
                 pages = int(self.last_io["pages"])
                 ppq = float(np.mean(self.last_io["pages_per_query"]))
@@ -769,14 +891,14 @@ class QueryExecutor:
             self._health = h
         return h
 
-    def _observed_rank_err(self, final: np.ndarray) -> float | None:
+    def _observed_rank_err(self, final: CandidateSets) -> float | None:
         """Observed rank-model error over this batch, as a fraction of
         the certified bound E (DESIGN.md §12).
 
         Samples up to ``_HEALTH_SAMPLE`` certified in-ring candidate
-        slots from the final mask (deterministic stride — no RNG on the
-        query path), recomputes their pivot distances from the rows
-        refinement just gathered (cache-hot), replays the kernel's
+        slots from the union of the final sets (deterministic stride —
+        no RNG on the query path), recomputes their pivot distances from
+        the rows refinement just gathered (cache-hot), replays the kernel's
         ``rank_math`` arithmetic in host f32 numpy, and compares the
         predicted ring id against the one the build stored.  Ratio 1.0
         means predictions are off by as much as the ring-widening
@@ -787,7 +909,7 @@ class QueryExecutor:
         s = self.snap
         K, n_max, m = s.rids.shape
         h = self._health_arrays()
-        slots = np.nonzero(final.any(axis=0) & h.in_ring)[0]
+        slots = np.flatnonzero(final.union(h.in_ring.size) & h.in_ring)
         if slots.size == 0:
             return None
         if slots.size > self._HEALTH_SAMPLE:
@@ -869,7 +991,7 @@ class QueryExecutor:
                 out = []
                 with span("executor.refine", {"B": plan.B}):
                     for b in range(Q.shape[0]):
-                        idx = np.nonzero(hit[b])[0]
+                        idx = hit[b]
                         ids = s.gids_np[idx]
                         d_true = dist_one_to_many(
                             Q[b], self._refine_rows(idx), "l2")
@@ -929,17 +1051,18 @@ class QueryExecutor:
         finally:
             self.backend.release(plan)
 
-    def _refine_topk(self, Q, final: np.ndarray, k_eff: int):
+    def _refine_topk(self, Q, final: CandidateSets, k_eff: int):
         """Exact f64 refinement of the certified candidate sets: the
         shared tail of both kNN backends.  ``final`` is a superset of the
-        closed k-th ball per query, so the stable distance sort selects
-        the same k results whichever backend produced it."""
+        closed k-th ball per query, so the stable distance sort (ties
+        in ascending slot order) selects the same k results whichever
+        backend produced it."""
         s = self.snap
         B = Q.shape[0]
         ids_out = np.empty((B, k_eff), np.int64)
         d_out = np.empty((B, k_eff))
         for b in range(B):
-            idx = np.nonzero(final[b])[0]
+            idx = final[b]
             d_true = dist_one_to_many(Q[b], self._refine_rows(idx), "l2")
             sel = np.argsort(d_true, kind="stable")[:k_eff]
             ids_out[b] = s.gids_np[idx[sel]]

@@ -24,7 +24,7 @@ import pytest
 
 from repro import obs
 from repro.core import LIMSIndex, MetricSpace
-from repro.core.executor import QueryExecutor, ShardedExecutor
+from repro.core.executor import QueryExecutor, ShardedExecutor, _pack_width
 from repro.core.metrics import dist_one_to_many
 from repro.core.snapshot import LIMSSnapshot
 from repro.obs import registry as _reg
@@ -486,15 +486,23 @@ def test_frontend_host_syncs_same_every_batch(setup, monkeypatch):
 def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
     """A routed batch's ``d2h_bytes`` is exactly what its copies hold:
     kNN — (B, K, m) f32 seed distances, (B, K) bool routing, the
-    (B, n_slots) bool certified mask and the int32 round count; range —
-    routing and the (B, n_slots) bool hits; with compaction on, the
-    host candidate mask it reads comes first, and the (B, bucket) uint8
-    ball replaces the hits unless the union is too large to gather."""
+    certified mask packed into (B, W) uint32 words and the int32 round
+    count; range — routing and the packed hits; with compaction on, the
+    (B, n_slots) bool host candidate mask it reads comes first, and the
+    (B, bucket) uint8 ball replaces the packed hits unless the union is
+    too large to gather.  No sync is added for the packing: kNN syncs 3
+    times (seed, routing, words and round count), range twice (routing,
+    hits) or, compacted, three times (routing, mask, ball); every batch
+    whose mask is computed on the device counts
+    ``executor.packed_batches``, every other ``executor.dense_batches``."""
     monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", compact)
     X, ix, snap, path, Q, rs = setup
     obs.configure("on")
     fe = _frontend(snap, 4)
+    packed_n = obs.REGISTRY.counter("executor.packed_batches")
+    dense_n = obs.REGISTRY.counter("executor.dense_batches")
+    before = packed_n.value, dense_n.value
     try:
         _serve_batch(fe, Q[:4], kind, 5 if kind == "knn" else float(rs[0]))
         ex = fe._router_obj.routing_ex
@@ -503,13 +511,20 @@ def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
         fe.close()
     s = ex.snap
     B, K, m = 4, s.K, s.m
-    want = B * K + B * s.n_slots
+    packed = 4 * B * _pack_width(s.n_slots)
+    want = B * K
+    lc = ex.last_compact
     if kind == "knn":
-        want += B * K * m * 4 + 4
+        want += B * K * m * 4 + packed + 4
     elif compact == "on":
-        lc = ex.last_compact
-        want += B * s.n_slots if lc is None else B * lc["bucket"]
+        want += B * s.n_slots + (packed if lc is None else B * lc["bucket"])
+    else:
+        want += packed
     assert p.d2h_bytes == want
+    assert p.host_syncs == (3 if kind == "knn" or compact == "on" else 2)
+    gathered = kind == "range" and compact == "on" and lc is not None
+    assert (packed_n.value, dense_n.value) == \
+        (before[0] + (not gathered), before[1] + gathered)
     assert p.stages["d2h"] > 0 and p.stages["route"] > 0
 
 
@@ -519,9 +534,9 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     and routing copies to one sub-batch: summed over the batch's
     profiles, ``d2h_bytes`` and ``host_syncs`` are exactly the batch's
     copies — kNN: the (B, K, m) f32 seed distances and (B, K) bool
-    routing once, then each sub-batch's (b, n_slots) bool mask and
+    routing once, then each sub-batch's packed (b, W) uint32 mask and
     int32 round count; range: the routing once, then each sub-batch's
-    (b, n_slots) bool hits."""
+    packed hits."""
     from repro.serving import ServingFrontend
     monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", "off")
@@ -539,12 +554,13 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     G = len(ps)
     assert G > 1                                 # the batch was split
     assert sum(p.batch for p in ps) == len(Q)
-    B, K, m, n_slots = len(Q), snap.K, snap.m, snap.n_slots
+    B, K, m = len(Q), snap.K, snap.m
+    packed = 4 * B * _pack_width(snap.n_slots)
     if kind == "knn":
-        want = B * K * m * 4 + B * K + B * n_slots + 4 * G
+        want = B * K * m * 4 + B * K + packed + 4 * G
         syncs = 2 + G
     else:
-        want = B * K + B * n_slots
+        want = B * K + packed
         syncs = 1 + G
     assert sum(p.d2h_bytes for p in ps) == want
     assert sum(p.host_syncs for p in ps) == syncs

@@ -82,3 +82,16 @@ def test_rankeval_compiles(pallas_lane, one_chip, g):
     """The staged plan's (G, 2B) boundary matrix; 13 groups pads."""
     _compile(lambda x, c, lo, hi, n: ops.rankeval(x, c, lo, hi, n),
              one_chip, (g, 2 * B), (g, C), (g,), (g,), (g,))
+
+
+@pytest.mark.parametrize("slots", [SLOTS, 1_752_064])
+def test_pack_mask_compiles_without_relayout(one_chip, slots):
+    """The candidate mask's bit-pack (``executor._pack_mask``) compiles
+    to passes over the mask as it lies: no copy and no scratch buffer
+    the size of the mask, which a relayout would need."""
+    from repro.core.executor import _pack_mask, _pack_width
+    mask = jax.ShapeDtypeStruct((B, slots), jnp.bool_, sharding=one_chip)
+    compiled = _pack_mask.lower(mask).compile()
+    assert compiled.out_info.shape == (B, _pack_width(slots))
+    assert " copy(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < B * slots // 8
