@@ -271,7 +271,7 @@ def main() -> None:
     from repro.serving import MonitorDaemon, PlanRouter, ReplicaSet
     snap = cold.executor.snap
     replicas = ReplicaSet(snap, n_replicas=4)
-    router = PlanRouter(replicas)
+    router = PlanRouter(replicas, max_batch=len(fresh))
     mon = Monitor(interval=3600.0)          # ticked by hand below
     daemon = MonitorDaemon(mon, lambda: router, engine=cold,
                            cooldown_ticks=3)

@@ -306,6 +306,13 @@ class CandidateSets:
         """(B,) candidates per query."""
         return np.diff(self.offsets)
 
+    def head(self, n: int) -> "CandidateSets":
+        """The first ``n`` queries' lists: a padded batch's real rows."""
+        if n == len(self):
+            return self
+        return CandidateSets(self.offsets[:n + 1],
+                             self.slots[:self.offsets[n]])
+
     def clusters(self, n_max: int) -> np.ndarray:
         """(B,) distinct clusters (``slot // n_max``) per query."""
         B = len(self)
@@ -838,7 +845,7 @@ class QueryExecutor:
         tp = time.perf_counter()
         with span("obs.profile"):
             s = self.snap
-            B = plan.B
+            B = plan.rows
             K, n_max, _ = s.rids.shape
             cost = plan.cost
             stages.update(route=cost.route_s, device_wait=cost.device_wait_s,
@@ -985,11 +992,11 @@ class QueryExecutor:
             with plan.cost.charge():
                 with span("executor.range_execute",
                           {"B": plan.B, "backend": self.backend.name}):
-                    hit = self.backend.range_hits(plan)
+                    hit = self.backend.range_hits(plan).head(plan.rows)
                 t1 = time.perf_counter()
                 stages["execute"] = t1 - t0
                 out = []
-                with span("executor.refine", {"B": plan.B}):
+                with span("executor.refine", {"B": plan.rows}):
                     for b in range(Q.shape[0]):
                         idx = hit[b]
                         ids = s.gids_np[idx]
@@ -1037,13 +1044,14 @@ class QueryExecutor:
                           {"B": plan.B, "k": plan.k,
                            "backend": self.backend.name}):
                     final, rounds = self.backend.knn_candidates(plan)
+                final = final.head(plan.rows)
                 t1 = time.perf_counter()
                 stages["execute"] = t1 - t0
                 self.last_knn = {"backend": self.backend.name, "k": plan.k,
                                  "rounds": rounds,
                                  "host_syncs": plan.cost.syncs,
                                  "driver": self.last_driver}
-                with span("executor.refine", {"B": plan.B}):
+                with span("executor.refine", {"B": plan.rows}):
                     out = self._refine_topk(Q, final, plan.k)
                 stages["refine"] = time.perf_counter() - t1
             self._emit_profile(plan, final, rounds, stages, t0)

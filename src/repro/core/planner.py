@@ -230,6 +230,15 @@ class CandidatePlan:
     # transfers, routing and compiles charged to this batch so far (a
     # router subset: see BatchCost and subset)
     cost: BatchCost = field(default_factory=BatchCost)
+    # trailing rows that copy the batch's first query so a router
+    # sub-batch keeps its replica's one device shape (``subset``'s
+    # ``pad_to``); the executor drops their results
+    pad: int = 0
+
+    @property
+    def rows(self) -> int:
+        """The batch's real queries: ``B`` less the padding rows."""
+        return self.B - self.pad
 
     @property
     def qf(self) -> jax.Array:
@@ -297,7 +306,8 @@ class CandidatePlan:
         return self._compact[0]
 
     def subset(self, idx: np.ndarray, planner: "Planner | None" = None,
-               device=None, shared: bool = True) -> "CandidatePlan":
+               device=None, shared: bool = True,
+               pad_to: int | None = None) -> "CandidatePlan":
         """The plan restricted to queries ``idx`` — what the router
         dispatches to a replica (one plan construction per batch still
         holds: a subset is a view, not a rebuild, and does not bump the
@@ -314,10 +324,19 @@ class CandidatePlan:
         The subset's cost record starts from a copy of this plan's when
         ``shared`` (it carries the batch's planning and routing) and
         from a fresh one otherwise.
+
+        ``pad_to`` appends copies of the first query of ``idx`` until
+        the subset has that many rows (``pad`` counts them): a copy
+        certifies and finishes its kNN schedule exactly when its
+        original does, so padding adds no round, and every sub-batch a
+        replica receives has the one shape its programs compiled for.
         """
         cost = dataclasses.replace(self.cost) if shared else BatchCost()
         with cost.charge():
             idx = np.asarray(idx, np.int64)
+            pad = max(int(pad_to) - len(idx), 0) if pad_to else 0
+            if pad:
+                idx = np.concatenate([idx, np.full(pad, idx[0])])
             qf = self._qf[jnp.asarray(idx)]
             if device is not None:
                 qf = jax.device_put(qf, device)
@@ -330,7 +349,7 @@ class CandidatePlan:
             _mask_np=None if self._mask_np is None else self._mask_np[idx],
             _routing_np=None if self._routing_np is None
             else self._routing_np[idx],
-            plan_s=self.plan_s, cost=cost)
+            plan_s=self.plan_s, cost=cost, pad=pad)
 
 
 class Planner:

@@ -6,7 +6,13 @@ from a host ``LIMSIndex`` — no query logic lives here (that is layer 2,
 ``repro.core.serving``; see DESIGN.md §1 for the stack).
 
 Everything a query needs is laid out per cluster, padded to a common
-``n_max`` so the whole corpus is one rectangular block:
+``n_max`` so the whole corpus is one rectangular block.  ``n_max`` is a
+multiple of 128, the TPU's lane width: flattening ``(…, K, n_max)`` to
+the ``K·n_max`` candidate axis is then free on the chip.  With a ragged
+``n_max`` the chip's compiler lowers that flattening of a
+``(B, K, n_max)`` mask as a long unrolled relayout: at 1M rows and
+K = 256 (n_max 6844) a planning program of over 60 MB that takes
+minutes to compile.
 
   rows    (K, n_max, d)  f32   ring-ordered store rows, then §5.3 insert-
                                buffer rows, then invalid padding slots
@@ -85,6 +91,8 @@ _DEVICE_FIELDS = (
     "valid", "in_ring", "always",
     "coef", "model_lo", "model_hi", "model_n", "rank_err",
 )
+# n_max is padded to a multiple of this (the TPU lane width)
+_SLOT_ALIGN = 128
 # static / host-side fields (pytree aux; the optional low-precision
 # plane rides as aux, not a child — its presence must not change the
 # pytree structure the sharded executor's cached shard_map builders key
@@ -177,7 +185,7 @@ class LIMSSnapshot:
         dead = index.tombstones
 
         n_slots = [ci.n + len(ci.buf_ids) for ci in index.clusters]
-        n_max = max(max(n_slots), 1)
+        n_max = -(-max(max(n_slots), 1) // _SLOT_ALIGN) * _SLOT_ALIGN
         rows = np.zeros((K, n_max, d), np.float32)
         rows64 = np.zeros((K, n_max, d), np.float64)
         rids = np.full((K, n_max, m), -1, np.int32)

@@ -68,7 +68,7 @@ def run_health_demo(st: SimpleNamespace | None = None, ticks: int = 10):
     st = st if st is not None else demo_state()
     snap = st.se.executor.snap
     replicas = ReplicaSet(snap, n_replicas=4)
-    router = PlanRouter(replicas)
+    router = PlanRouter(replicas, max_batch=len(st.Q))
     # interval is irrelevant — the demo ticks manually, nothing starts
     # the sampler thread, so the loop below is fully deterministic
     mon = Monitor(interval=3600.0)

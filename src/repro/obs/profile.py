@@ -21,7 +21,13 @@ inferred from benchmarks.  Every executed batch therefore yields one
   execution, exact refinement, building this record, and the total;
   ``device_wait`` and ``d2h`` are the batch's host waits for device
   values and its copies, sums that overlap ``plan``/``route``/
-  ``execute``.
+  ``execute``;
+* **dispatch** — on the profile of a routed batch's first sub-batch,
+  filled in by the router once every sub-batch has joined: the wall
+  time of the threaded dispatch, the sum of the sub-batches' own
+  seconds (each one's thread CPU time plus its waits on its device;
+  their ratio is how far the replicas overlapped), and the batch's real
+  and padding rows.
 
 Profiles land in a bounded ring (``REPRO_OBS_PROFILES`` records,
 default 256 — a serving window, not a log) and feed the registry's
@@ -77,6 +83,14 @@ class QueryProfile:
     rank_err_ratio: float | None = None
     d2h_bytes: int = 0           # bytes copied device→host for the batch
     compiles: int = 0            # backend compiles during the batch
+    # the router's record of the whole batch, on its first sub-batch's
+    # profile only (None elsewhere): dispatch wall seconds, the sum of
+    # the sub-batches' own seconds (thread CPU + device waits), real
+    # rows and padding rows dispatched
+    dispatch_s: float | None = None
+    subbatch_s: float | None = None
+    rows: int | None = None
+    pad_rows: int | None = None
 
     def as_dict(self) -> dict:
         return {
@@ -95,6 +109,11 @@ class QueryProfile:
             "stages_ms": {k: round(v * 1e3, 3)
                           for k, v in self.stages.items()},
             "total_ms": round(self.total_s * 1e3, 3),
+            "dispatch_ms": (round(self.dispatch_s * 1e3, 3)
+                            if self.dispatch_s is not None else None),
+            "subbatch_ms": (round(self.subbatch_s * 1e3, 3)
+                            if self.subbatch_s is not None else None),
+            "rows": self.rows, "pad_rows": self.pad_rows,
         }
 
     def missing(self) -> list:

@@ -281,7 +281,7 @@ class ServingFrontend:
             with span("frontend.replica_rebuild", {"generation": gen}):
                 self._router_obj = PlanRouter(ReplicaSet(
                     ex.snap, n_replicas=self._n_replicas,
-                    prefetch=self._prefetch))
+                    prefetch=self._prefetch), max_batch=self._max_batch)
             _obs.count("frontend.replica_rebuilds")
             self._gen = gen
         return self._router_obj

@@ -16,19 +16,23 @@ maps clusters → mesh ``data`` axis (each device holds a shard and
 collectives merge per-round reductions), a replica set gives every
 device the whole cluster axis and partitions the *request* stream
 instead — the router sends each query sub-batch to one replica, chosen
-by TriPrune cluster ownership.  Both placements preserve exactness for
-free (per-cluster state is self-contained; per-query results are
-independent of batchmates); replication trades memory for routing
-freedom and zero cross-device collectives on the hot path.
+by TriPrune cluster ownership; resident replicas each take at most a
+fixed capacity, and their sub-batches are padded to it so each device
+keeps one program shape (``serving/router.py``).  Both placements
+preserve exactness for free (per-cluster state is self-contained;
+per-query results are independent of batchmates); replication trades
+memory for routing freedom and zero cross-device collectives on the
+hot path.
 
 Cluster *ownership* is the routing preference, not a data partition:
 every replica can execute any query bit-identically; ownership decides
-which replica a query's TriPrune cluster set votes for.  The default is
-round-robin (cluster k → replica k mod R); :meth:`ReplicaSet.rebalance`
-reassigns ownership greedily from a cluster-heat signal — by default
-the page cache's access counters folded per extent
-(``PagedStore.cluster_heat``), closing the storage → placement feedback
-loop (DESIGN.md §9).
+which replica a query's TriPrune cluster set votes for, and a query
+goes to its most-voted replica (among resident replicas, while that one
+has room).  The default is round-robin (cluster k → replica k mod R);
+:meth:`ReplicaSet.rebalance` reassigns ownership greedily from a
+cluster-heat signal — by default the page cache's access counters
+folded per extent (``PagedStore.cluster_heat``), closing the storage →
+placement feedback loop (DESIGN.md §9).
 """
 from __future__ import annotations
 

@@ -119,10 +119,10 @@ def test_router_bit_identical_and_one_plan(setup):
     batch (subsetting never re-plans)."""
     X, ix, snap, path = setup
     direct = QueryExecutor(snap)
-    router = PlanRouter(ReplicaSet(snap, n_replicas=3))
     Q = _queries(X, 12, seed=5)
+    router = PlanRouter(ReplicaSet(snap, n_replicas=3), max_batch=len(Q))
     rs = _radii(X, Q)
-    rs[0] = 1e-12                            # unrouted → round-robin
+    rs[0] = 1e-12                            # unrouted → least loaded
     before = router.routing_ex.planner.built
     got = router.range_query_batch(Q, rs)
     assert router.routing_ex.planner.built == before + 1
@@ -149,8 +149,8 @@ def test_router_paged_bit_identical(setup):
     X, ix, snap, path = setup
     direct = QueryExecutor(snap)
     paged = LIMSSnapshot.load(path, store=True, cache_pages=8)
-    router = PlanRouter(ReplicaSet(paged, n_replicas=2))
     Q = _queries(X, 8, seed=7)
+    router = PlanRouter(ReplicaSet(paged, n_replicas=2), max_batch=len(Q))
     ids_r, ds_r = router.knn_query_batch(Q, 6)
     ids_d, ds_d = direct.knn_query_batch(Q, 6)
     assert np.array_equal(ids_r, ids_d)
@@ -172,12 +172,213 @@ def test_router_replica_error_reaches_caller(setup):
     """An executor failure inside a routed sub-batch re-raises on the
     calling thread, never silently drops queries."""
     X, ix, snap, path = setup
-    router = PlanRouter(ReplicaSet(snap, n_replicas=1))
+    router = PlanRouter(ReplicaSet(snap, n_replicas=1), max_batch=3)
     def boom(Q, plan):
         raise RuntimeError("replica died")
     router.replicas.members[0].ex.execute_knn = boom
     with pytest.raises(RuntimeError, match="replica died"):
         router.knn_query_batch(_queries(X, 3, seed=9), 4)
+
+
+# ------------------------------------- capacity-bounded, padded sub-batches
+MB, R4 = 16, 4                  # cap = ceil(16 / 4) = 4 → 8 rows (x8)
+
+
+def _padded_router(snap, n_replicas=R4, max_batch=MB):
+    """A router whose replicas record each sub-plan they execute."""
+    router = PlanRouter(ReplicaSet(snap, n_replicas=n_replicas),
+                        max_batch=max_batch)
+    seen = []
+    for rep in router.replicas.members:
+        for name in ("execute_knn", "execute_range"):
+            fn = getattr(rep.ex, name)
+
+            def rec(Q, plan, _fn=fn, _rid=rep.rid):
+                seen.append((_rid, plan.B, plan.pad, len(Q)))
+                return _fn(Q, plan)
+            setattr(rep.ex, name, rec)
+    return router, seen
+
+
+def _scan_answers(X, Q, kind, arg):
+    from repro.baselines.linear_scan import LinearScan
+    scan = LinearScan(MetricSpace(X, "l2"))
+    if kind == "knn":
+        return [scan.knn_query(q, arg)[:2] for q in Q]
+    return [scan.range_query(q, r)[:2] for q, r in zip(Q, arg)]
+
+
+def _by_dist(ids, ds):
+    o = np.lexsort((ids, ds))
+    return ids[o], ds[o]
+
+
+@pytest.mark.parametrize("kind", ["knn1", "knn10", "range"])
+@pytest.mark.parametrize("B", [1, 3, 4, 9, 16])
+def test_padded_subbatches_bit_identical(setup, B, kind):
+    """Four replicas, batches up to 16: whatever B, every sub-batch runs
+    at the cap of 8 rows, and the answers equal the direct executor's
+    and a linear scan's, ids and f64 distances bit for bit."""
+    X, ix, snap, path = setup
+    router, seen = _padded_router(snap)
+    Q = _queries(X, B, seed=100 + B)
+    direct = QueryExecutor(snap)
+    if kind == "range":
+        rs = _radii(X, Q)
+        got = router.range_query_batch(Q, rs)
+        ref = direct.range_query_batch(Q, rs)
+        scan = _scan_answers(X, Q, "range", rs)
+        for (gi, gd), (ri, rd), (si, sd) in zip(got, ref, scan):
+            assert np.array_equal(gi, ri) and np.array_equal(gd, rd)
+            gi, gd = _by_dist(gi, gd)
+            si, sd = _by_dist(si, sd)
+            assert np.array_equal(gi, si) and np.array_equal(gd, sd)
+    else:
+        k = int(kind[3:])
+        ids, ds = router.knn_query_batch(Q, k)
+        ids_d, ds_d = direct.knn_query_batch(Q, k)
+        assert np.array_equal(ids, ids_d) and np.array_equal(ds, ds_d)
+        for b, (si, sd) in enumerate(_scan_answers(X, Q, "knn", k)):
+            assert np.array_equal(ids[b], si) and np.array_equal(ds[b], sd)
+    assert sum(n for *_, n in seen) == B
+    assert {pb for _, pb, _, _ in seen} == {8}
+    assert all(pad == 8 - n for _, _, pad, n in seen)
+
+
+@pytest.mark.parametrize("driver", ["rounds", "loop"])
+def test_padding_never_adds_knn_rounds(setup, monkeypatch, driver):
+    """A padding row copies a real query of its sub-batch, so it
+    certifies when that query does: each sub-batch takes as many rounds
+    as its slowest real query alone."""
+    monkeypatch.setenv("REPRO_KNN_DRIVER", driver)
+    X, ix, snap, path = setup
+    router, seen = _padded_router(snap)
+    Q = _queries(X, 11, seed=41, scale=0.05)
+    direct = QueryExecutor(snap)
+    solo = []
+    for q in Q:
+        direct.knn_query_batch(q[None], 10)
+        solo.append(direct.last_knn["rounds"])
+    pick = router._assign(router.routing_ex.planner.plan_knn(
+        _pad_rows(Q, MB), 10, 64), len(Q), 8)
+    router.knn_query_batch(Q, 10)
+    assert any(pad for _, _, pad, _ in seen)
+    for rep in router.replicas.members:
+        idx = np.nonzero(pick == rep.rid)[0]
+        if len(idx):
+            assert rep.ex.last_knn["driver"] == driver
+            assert rep.ex.last_knn["rounds"] == max(solo[i] for i in idx)
+
+
+def _pad_rows(Q, n):
+    return np.concatenate([Q, np.repeat(Q[:1], n - len(Q), axis=0)])
+
+
+def test_varied_batch_sizes_compile_nothing_after_warm(setup):
+    """After one kNN batch at max_batch (32: four replicas, each filled
+    to its cap of 8), batches of any size compile no program: the batch
+    is planned at 32 rows and every sub-batch runs at 8."""
+    from repro.obs import registry as obs
+    X, ix, snap, path = setup
+    obs.configure("on")
+    router, seen = _padded_router(snap, max_batch=32)
+    router.knn_query_batch(_queries(X, 32, seed=60), 10)
+    assert len({rid for rid, *_ in seen}) == R4
+    direct = QueryExecutor(snap)
+    Qs = [_queries(X, B, seed=60 + B) for B in (1, 3, 4, 9, 16, 23, 32)]
+    refs = [direct.knn_query_batch(Q, 10) for Q in Qs]
+    c0 = obs.REGISTRY.counter("jax.backend_compiles").value
+    got = [router.knn_query_batch(Q, 10) for Q in Qs]
+    assert obs.REGISTRY.counter("jax.backend_compiles").value == c0
+    for (ids, ds), (ids_d, ds_d) in zip(got, refs):
+        assert np.array_equal(ids, ids_d) and np.array_equal(ds, ds_d)
+    assert {pb for _, pb, _, _ in seen} == {8}
+
+
+@pytest.mark.parametrize("B", [1, 5, 16])
+def test_one_replica_dispatches_unpadded(setup, B):
+    """One replica: the batch is planned and executed at its own size,
+    with no padding, exactly as it came."""
+    X, ix, snap, path = setup
+    router, seen = _padded_router(snap, n_replicas=1)
+    assert router.shapes(B) == (B, 0)
+    Q = _queries(X, B, seed=70 + B)
+    ids, ds = router.knn_query_batch(Q, 5)
+    rs = _radii(X, Q)
+    router.range_query_batch(Q, rs)
+    assert seen == [(0, B, 0, B), (0, B, 0, B)]
+    ids_d, ds_d = QueryExecutor(snap).knn_query_batch(Q, 5)
+    assert np.array_equal(ids, ids_d) and np.array_equal(ds, ds_d)
+
+
+def test_paged_replicas_route_by_ownership_unpadded(setup):
+    """Paged replicas take neither the cap nor padding: with every
+    cluster owned by replica 0, the whole batch goes there, planned and
+    executed at its own size, and stays bit-identical."""
+    X, ix, snap, path = setup
+    paged = LIMSSnapshot.load(path, store=True, cache_pages=8)
+    router, seen = _padded_router(paged, max_batch=8)
+    router.replicas.set_ownership(np.zeros(paged.K, np.int64))
+    Q = _queries(X, 8, seed=75)
+    assert router.shapes(len(Q)) == (len(Q), 0)
+    ids, ds = router.knn_query_batch(Q, 5)
+    assert seen == [(0, len(Q), 0, len(Q))]
+    ids_d, ds_d = QueryExecutor(snap).knn_query_batch(Q, 5)
+    assert np.array_equal(ids, ids_d) and np.array_equal(ds, ds_d)
+    assert paged.store.cache.pinned == 0
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_capacity_assign_bounds_and_preference(seed):
+    """No replica takes more than cap; every query is placed; a query
+    off its first preference (most votes, then least load) finds that
+    replica full; and a full replica kept the queries that vote for it
+    most."""
+    from repro.serving.router import capacity_assign
+    rng = np.random.default_rng(seed)
+    R = int(rng.integers(2, 6))
+    cap = int(rng.integers(1, 9))
+    B = int(rng.integers(1, R * cap + 1))
+    votes = rng.integers(0, 4, (B, R)) * rng.integers(0, 2, (B, 1))
+    load = rng.integers(0, 50, R)
+    pick = capacity_assign(votes, load, cap)
+    assert pick.shape == (B,) and pick.min() >= 0 and pick.max() < R
+    counts = np.bincount(pick, minlength=R)
+    assert counts.max() <= cap
+    rank = np.argsort(np.argsort(load, kind="stable"), kind="stable")
+    first = np.argmax(votes * R - rank[None, :], axis=1)
+    for q in np.nonzero(pick != first)[0]:
+        r = first[q]
+        assert counts[r] == cap
+        assert votes[pick == r, r].min() >= votes[q, r]
+
+
+def test_router_records_dispatch_on_first_subbatch(setup):
+    """A routed batch's dispatch record lands on its first sub-batch's
+    profile: dispatch wall time, the sub-batches' own seconds, real and
+    padding rows; the router's row counters follow."""
+    from repro.obs import profile as prof
+    from repro.obs import registry as obs
+    X, ix, snap, path = setup
+    obs.configure("on")
+    router = PlanRouter(ReplicaSet(snap, n_replicas=R4), max_batch=MB)
+    rows0 = obs.REGISTRY.counter("router.rows").value
+    pad0 = obs.REGISTRY.counter("router.pad_rows").value
+    n0 = len(prof.profiles())
+    router.knn_query_batch(_queries(X, 9, seed=80), 5)
+    ps = prof.profiles()[n0:]
+    first = [p for p in ps if p.dispatch_s is not None]
+    assert len(first) == 1 and len(ps) >= 2
+    p = first[0]
+    assert p.rows == 9 and p.pad_rows == 8 * len(ps) - 9
+    # own seconds exclude waits for the interpreter: at most every
+    # sub-batch working for the whole dispatch
+    assert p.dispatch_s > 0 and 0 < p.subbatch_s <= len(ps) * p.dispatch_s
+    assert sum(q.batch for q in ps) == 9
+    assert p.as_dict()["pad_rows"] == p.pad_rows
+    assert obs.REGISTRY.counter("router.rows").value - rows0 == 9
+    assert obs.REGISTRY.counter("router.pad_rows").value - pad0 == \
+        p.pad_rows
 
 
 # ---------------------------------------------------------------- frontend

@@ -359,7 +359,7 @@ def _drift_stack(snap, n_replicas=4, cooldown=2, **daemon_kw):
     process registry (obs must be "on") because the router publishes
     its heat-skew gauge there — exactly the production wiring."""
     replicas = ReplicaSet(snap, n_replicas=n_replicas)
-    router = PlanRouter(replicas)
+    router = PlanRouter(replicas, max_batch=64)
     mon = Monitor(interval=3600.0,
                   detectors=[HeatSkewDetector(trigger=1.5, clear=1.15,
                                               persistence=2),
@@ -458,7 +458,7 @@ def test_closed_loop_paged_drift_to_rebalance_bit_identical(setup):
     obs.REGISTRY.reset()            # deterministic reservoirs for p50s
     paged = LIMSSnapshot.load(path, store=True, cache_pages=8)
     replicas = ReplicaSet(paged, n_replicas=4)
-    router = PlanRouter(replicas)
+    router = PlanRouter(replicas, max_batch=len(Q))
     mon = Monitor(interval=3600.0,
                   detectors=[HeatSkewDetector(trigger=1.5, clear=1.15,
                                               persistence=2)])

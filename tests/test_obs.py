@@ -533,10 +533,11 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     """A batch the router splits across replicas charges its planning
     and routing copies to one sub-batch: summed over the batch's
     profiles, ``d2h_bytes`` and ``host_syncs`` are exactly the batch's
-    copies — kNN: the (B, K, m) f32 seed distances and (B, K) bool
-    routing once, then each sub-batch's packed (b, W) uint32 mask and
-    int32 round count; range: the routing once, then each sub-batch's
-    packed hits."""
+    copies — kNN: the (n, K, m) f32 seed distances and (n, K) bool
+    routing once, at the n rows the batch is planned at, then each
+    sub-batch's packed (cap, W) uint32 mask, padded to the replica's
+    cap rows, and int32 round count; range: the routing once, then
+    each sub-batch's packed hits."""
     from repro.serving import ServingFrontend
     monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", "off")
@@ -554,13 +555,14 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     G = len(ps)
     assert G > 1                                 # the batch was split
     assert sum(p.batch for p in ps) == len(Q)
-    B, K, m = len(Q), snap.K, snap.m
-    packed = 4 * B * _pack_width(snap.n_slots)
+    K, m = snap.K, snap.m
+    n, cap = fe._router_obj.shapes(len(Q))
+    packed = 4 * G * cap * _pack_width(snap.n_slots)
     if kind == "knn":
-        want = B * K * m * 4 + B * K + packed + 4 * G
+        want = n * K * m * 4 + n * K + packed + 4 * G
         syncs = 2 + G
     else:
-        want = B * K + packed
+        want = n * K + packed
         syncs = 1 + G
     assert sum(p.d2h_bytes for p in ps) == want
     assert sum(p.host_syncs for p in ps) == syncs
