@@ -161,8 +161,26 @@ def _knn_rounds(qf, d2, kth0, r0, eps, snap, n_rings, k_eff, max_rounds,
     certified set is a superset of the closed k-th ball at any radius
     the schedule visits, and exactness never depends on the seed.
     Anything the schedule never certifies falls back to the exact full
-    scan of (locally) valid slots.  Returns (final mask, rounds used),
-    both shard-local shapes under ``shard_map``.
+    scan of (locally) valid slots.
+
+    The mask returned is that set ANDed with the k-th-distance band
+    (``_knn_band``, ``ball(r_t)``), so refinement gets the ~k rows that
+    can be answers, not the whole certified ball.  ``r_t`` is the
+    smallest f32 radius at which the certification inequality above
+    holds for ``kth0``, the f32 k-th distance over every valid slot.
+    Sound by the argument that makes ``ball(r)`` sound (DESIGN.md §8):
+    k valid slots have filter distance ≤ ``kth0``, the inequality at
+    ``r_t`` is the guard band that puts their true distances at ≤
+    ``r_t``, so the true k-th distance D_k ≤ ``r_t``, and every slot
+    whose true distance is ≤ D_k passes ``ball(r_t)``.  The certified
+    set, or the fallback's valid set, holds that closed k-th ball too,
+    so their intersection does, and the stable f64 sort picks the same
+    ids and distances from it.  Under ``shard_map`` each shard bands
+    its slots with the global ``kth0``.
+
+    Returns (banded mask, rounds used, (B,) rows and (B,) clusters of
+    the certified set before the band) — the counts are global (reduced
+    with ``count_sum``), the mask shard-local under ``shard_map``.
 
     ``eps`` is the certified quantization margin of whatever row plane
     produced ``d2`` (``snap.filter_rows()``; 0.0 for the exact f32
@@ -201,14 +219,38 @@ def _knn_rounds(qf, d2, kth0, r0, eps, snap, n_rings, k_eff, max_rounds,
            jnp.zeros((B, valid.shape[0]), bool))
     done, _, rounds, final = jax.lax.while_loop(cond, body, st0)
     final = jnp.where(done[:, None], final, valid[None])
-    return final, rounds
+    cnt = count_sum(final)
+    clusters = count_sum(final.reshape((B,) + snap.valid.shape).any(-1))
+    return final & _knn_band(d2, kth0, eps), rounds, cnt, clusters
+
+
+@jax.jit
+def _knn_band(d2, kth0, eps):
+    """(B, P) bool: the kNN loop's k-th-distance band (soundness in
+    ``_knn_rounds``) — the slots whose filter distance passes the ball
+    test at ``r_t``, the smallest f32 radius at which the loop's
+    certification inequality ``kth0 <= r_t·(1 − _R_REL) − _BALL_ABS −
+    eps`` holds.  ``r_t`` is ``(kth0 + _BALL_ABS + eps) / (1 − _R_REL)``
+    rounded up in f32 one ulp at a time until the inequality holds as
+    the loop evaluates it (two steps at most, measured over 2·10⁷ values
+    of ``kth0`` from 0 to 300; a third is spare).  A row where it still
+    fails, or where ``kth0`` is not finite, is left unbanded."""
+    def cert(r):
+        return kth0 <= r * (1.0 - _R_REL) - _BALL_ABS - eps
+
+    r = (kth0 + _BALL_ABS + eps) * (1.0 / (1.0 - _R_REL))
+    for _ in range(3):
+        r = jnp.where(cert(r), r, jnp.nextafter(r, jnp.inf))
+    ball = d2 <= ((r * (1.0 + _R_REL) + _BALL_ABS + eps) ** 2)[:, None]
+    return ball | ~cert(r)[:, None]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_rings", "k_eff", "max_rounds"))
 def _knn_loop_single(qf, d2, kth0, r0, eps, *arrays, n_rings, k_eff,
                      max_rounds):
-    """Single-device compiled kNN rounds: (final mask, rounds used).
+    """Single-device compiled kNN rounds: (banded mask, rounds used,
+    certified rows, certified clusters) — see ``_knn_rounds``.
 
     ``d2``/``kth0`` (the full valid-masked filter-plane distance matrix
     and the f32 k-th distance) arrive precomputed from the *eager*
@@ -453,15 +495,20 @@ class _ResidentBackend:
         return CandidateSets.from_mask(hits, slots)
 
     def knn_candidates(self, plan: CandidatePlan):
+        """(banded candidate sets, rounds, (certified rows, certified
+        clusters) per query): the sets refinement scans, and the
+        pruning power of the LIMS-certified sets they were cut from."""
         ex = self.ex
         if _knn_driver(ex) == "rounds":
             return self._knn_host_rounds(plan)
         ex.last_driver = "loop"
         r0 = jnp.asarray(plan.radii, jnp.float32)
-        final, rounds = ex._knn_device_loop(
+        final, rounds, cnt, clusters = ex._knn_device_loop(
             plan.qf, r0, plan.k, plan.max_rounds)
-        words, rounds = plan.cost.to_host((_pack_mask(final), rounds))
-        return CandidateSets.from_packed(words), int(rounds)
+        words, rounds, cnt, clusters = plan.cost.to_host(
+            (_pack_mask(final), rounds, cnt, clusters))
+        return CandidateSets.from_packed(words), int(rounds), \
+            (cnt, clusters)
 
     def _knn_host_rounds(self, plan: CandidatePlan):
         """The same certified schedule as ``_knn_rounds``, driven from
@@ -471,7 +518,8 @@ class _ResidentBackend:
         host syncs for XLA-CPU's fast eager lowerings.  The certified
         set is a superset of the closed k-th ball at whatever schedule
         radius certifies, so refinement returns bit-identical results
-        whichever driver ran (pinned by tests)."""
+        whichever driver ran (pinned by tests); the k-th-distance band
+        is the loop's own ``_knn_band``, so the sets are identical too."""
         ex = self.ex
         s = ex.snap
         qf = plan.qf
@@ -512,7 +560,10 @@ class _ResidentBackend:
         else:
             final[~done] = s.valid_np[None]
         ex.last_driver = "rounds"
-        return CandidateSets.from_mask(final), rounds
+        cnt = final.sum(axis=1)
+        clusters = final.reshape((B,) + s.valid.shape).any(-1).sum(-1)
+        final &= cost.to_host(_knn_band(d2, kth0, jnp.float32(eps)))
+        return CandidateSets.from_mask(final), rounds, (cnt, clusters)
 
 
 class _PagedBackend:
@@ -701,7 +752,7 @@ class _PagedBackend:
                       "pinned_pages": sum(len(p) for p in plan._pins)}
         if pf is not None:
             ex.last_io["prefetch"] = pf.snapshot()
-        return CandidateSets.from_mask(final), rounds
+        return CandidateSets.from_mask(final), rounds, None
 
 
 # ---------------------------------------------------------------------------
@@ -812,7 +863,8 @@ class QueryExecutor:
         return jnp.where(s.valid.reshape(-1)[None], d2, jnp.inf), eps
 
     def _knn_device_loop(self, qf, r0, k_eff: int, max_rounds: int):
-        """(final mask, rounds) — the kNN schedule as one executable.
+        """(banded mask, rounds, certified rows, certified clusters) —
+        the kNN schedule as one executable (``_knn_rounds``).
 
         The loop-invariant pieces (filter-plane distance matrix, seed
         k-th distance) run on the eager kernel path first; only the
@@ -828,7 +880,8 @@ class QueryExecutor:
 
     # -------------------------------------------------------- observability
     def _emit_profile(self, plan: CandidatePlan, final: CandidateSets,
-                      rounds: int, stages: dict, t0: float) -> None:
+                      rounds: int, stages: dict, t0: float,
+                      certified=None) -> None:
         """Build and record one batch's :class:`QueryProfile`.
 
         Everything derives from state already on the host — the final
@@ -836,10 +889,13 @@ class QueryExecutor:
         :class:`~repro.core.planner.BatchCost` — so profiling adds
         *zero* device syncs (the planner's O(1)-syncs-per-batch contract
         is pinned by tests and must survive instrumentation).
-        Candidates here are the certified rows refinement actually
-        scanned; clusters are how many of the K clusters those rows
-        span (TriPrune's pruning power, per query).  The time spent
-        here before the record is filed is its ``profile`` stage."""
+        Candidates are the LIMS-certified rows per query and clusters
+        how many of the K clusters those rows span (TriPrune's pruning
+        power): ``certified`` gives their (B,) counts where refinement
+        got a tighter set (the kNN band), else ``final`` is that set.
+        ``refined_per_query`` counts the rows refinement scanned.  The
+        time spent here before the record is filed is its ``profile``
+        stage."""
         if not _obs.enabled():
             return
         tp = time.perf_counter()
@@ -850,8 +906,10 @@ class QueryExecutor:
             cost = plan.cost
             stages.update(route=cost.route_s, device_wait=cost.device_wait_s,
                           d2h=cost.d2h_s)
-            cand = final.counts
-            clusters = final.clusters(n_max)
+            if certified is None:
+                cand, clusters = final.counts, final.clusters(n_max)
+            else:
+                cand, clusters = (c[:B] for c in certified)
             if self.backend.name == "paged" and self.last_io is not None:
                 pages = int(self.last_io["pages"])
                 ppq = float(np.mean(self.last_io["pages_per_query"]))
@@ -867,6 +925,7 @@ class QueryExecutor:
                 pages=pages, pages_per_query=ppq,
                 candidates_per_query=float(cand.mean()),
                 clusters_per_query=float(clusters.mean()),
+                refined_per_query=float(final.counts.mean()),
                 n_clusters=int(K), stages=stages,
                 total_s=time.perf_counter() - t0 + plan.plan_s,
                 rank_err_ratio=self._observed_rank_err(final),
@@ -902,8 +961,8 @@ class QueryExecutor:
         """Observed rank-model error over this batch, as a fraction of
         the certified bound E (DESIGN.md §12).
 
-        Samples up to ``_HEALTH_SAMPLE`` certified in-ring candidate
-        slots from the union of the final sets (deterministic stride —
+        Samples up to ``_HEALTH_SAMPLE`` in-ring candidate slots from
+        the union of the sets refinement scanned (deterministic stride —
         no RNG on the query path), recomputes their pivot distances from
         the rows refinement just gathered (cache-hot), replays the kernel's
         ``rank_math`` arithmetic in host f32 numpy, and compares the
@@ -1043,7 +1102,8 @@ class QueryExecutor:
                 with span("executor.knn_execute",
                           {"B": plan.B, "k": plan.k,
                            "backend": self.backend.name}):
-                    final, rounds = self.backend.knn_candidates(plan)
+                    final, rounds, certified = \
+                        self.backend.knn_candidates(plan)
                 final = final.head(plan.rows)
                 t1 = time.perf_counter()
                 stages["execute"] = t1 - t0
@@ -1054,17 +1114,18 @@ class QueryExecutor:
                 with span("executor.refine", {"B": plan.rows}):
                     out = self._refine_topk(Q, final, plan.k)
                 stages["refine"] = time.perf_counter() - t1
-            self._emit_profile(plan, final, rounds, stages, t0)
+            self._emit_profile(plan, final, rounds, stages, t0, certified)
             return out
         finally:
             self.backend.release(plan)
 
     def _refine_topk(self, Q, final: CandidateSets, k_eff: int):
-        """Exact f64 refinement of the certified candidate sets: the
-        shared tail of both kNN backends.  ``final`` is a superset of the
-        closed k-th ball per query, so the stable distance sort (ties
-        in ascending slot order) selects the same k results whichever
-        backend produced it."""
+        """Exact f64 refinement of the candidate sets: the shared tail
+        of both kNN backends.  ``final`` is a superset of the closed
+        k-th ball per query (certified, and on the resident backend
+        banded), so the stable distance sort (ties in ascending slot
+        order) selects the same k results whichever backend produced
+        it."""
         s = self.snap
         B = Q.shape[0]
         ids_out = np.empty((B, k_eff), np.int64)
@@ -1272,7 +1333,7 @@ def _sharded_knn_loop(mesh: Mesh, axis: str, n_rings: int, specs: tuple,
 
     return jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(rep, rep) + specs,
-                                 out_specs=(P(None, axis), P()),
+                                 out_specs=(P(None, axis), P(), P(), P()),
                                  check_vma=False))
 
 

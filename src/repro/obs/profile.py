@@ -12,7 +12,9 @@ inferred from benchmarks.  Every executed batch therefore yields one
 * **pruning power** — candidates certified per query and clusters the
   certified set touches per query (out of K), i.e. how hard TriPrune +
   the ring box actually pruned *this* batch — the signal the
-  curse-of-dimensionality results say must be measured per query;
+  curse-of-dimensionality results say must be measured per query —
+  and the rows exact refinement then scanned per query (kNN: the
+  certified set cut to the k-th-distance band);
 * **rounds / syncs / transfers / compiles** — growing-radius rounds,
   device→host syncs and the bytes they copied, and backend compiles
   during the batch, all counted on the batch's own cost record
@@ -51,7 +53,7 @@ REQUIRED_FIELDS = (
     "kind", "batch", "backend", "storage", "n_shards", "rounds",
     "host_syncs", "pages", "pages_per_query", "candidates_per_query",
     "clusters_per_query", "n_clusters", "stages", "total_s", "d2h_bytes",
-    "compiles",
+    "compiles", "refined_per_query",
 )
 REQUIRED_STAGES = ("plan", "route", "execute", "refine", "device_wait",
                    "d2h", "profile")
@@ -74,6 +76,9 @@ class QueryProfile:
     pages_per_query: float       # the paper's IO metric
     candidates_per_query: float  # certified candidate rows / query
     clusters_per_query: float    # clusters the certified set spans / query
+    # rows the exact f64 refinement scanned / query: the kNN sets after
+    # the k-th-distance band (a few more than k), the range hits
+    refined_per_query: float
     n_clusters: int              # K, for interpreting the pruning power
     stages: dict = field(default_factory=dict)   # stage → seconds
     total_s: float = 0.0
@@ -103,6 +108,7 @@ class QueryProfile:
             "pages_per_query": round(self.pages_per_query, 3),
             "candidates_per_query": round(self.candidates_per_query, 2),
             "clusters_per_query": round(self.clusters_per_query, 2),
+            "refined_per_query": round(self.refined_per_query, 2),
             "n_clusters": self.n_clusters,
             "rank_err_ratio": (round(self.rank_err_ratio, 4)
                                if self.rank_err_ratio is not None else None),

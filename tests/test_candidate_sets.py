@@ -180,9 +180,13 @@ def test_range_matches_golden_and_host_with_ties(name, compact,
 @pytest.mark.parametrize("kind", ["knn", "range"])
 def test_profile_reads_what_the_dense_mask_gives(kind, monkeypatch):
     """A batch's ``candidates_per_query`` and ``clusters_per_query``
-    equal what its dense mask gives (the mask as it was before packing,
-    recomputed here with ``np.nonzero``), and ``rank_err_ratio`` is
-    computed over the same sampled slots the dense mask's union gives."""
+    equal what its LIMS-certified dense mask gives (kNN: the loop's
+    ``cand ∧ ball`` set, recomputed here by the same loop with the
+    k-th-distance band taken out; range: the hits), recomputed with
+    ``np.nonzero``; ``refined_per_query`` equals what the packed mask
+    refinement read gives (kNN: the certified set cut to the band); and
+    ``rank_err_ratio`` is computed over the same sampled slots the
+    packed mask's union gives."""
     monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", "off")
     X, ix, snap, exs = _env()
@@ -195,6 +199,19 @@ def test_profile_reads_what_the_dense_mask_gives(kind, monkeypatch):
         masks.append(np.asarray(mask))
         return pack(mask)
     monkeypatch.setattr(executor_mod, "_pack_mask", keep)
+    certified = []
+    loop, band = executor_mod._knn_loop_single, executor_mod._knn_band
+
+    def loop_keeping_certified(*a, **kw):
+        # the same loop, run eagerly with an all-True band
+        monkeypatch.setattr(executor_mod, "_knn_band", lambda *_: True)
+        try:
+            certified.append(np.asarray(loop.__wrapped__(*a, **kw)[0]))
+        finally:
+            monkeypatch.setattr(executor_mod, "_knn_band", band)
+        return loop(*a, **kw)
+    monkeypatch.setattr(executor_mod, "_knn_loop_single",
+                        loop_keeping_certified)
     gathered = []                         # slot arrays refinement rows
     rows = ex._refine_rows                # are read for, in call order
 
@@ -205,20 +222,27 @@ def test_profile_reads_what_the_dense_mask_gives(kind, monkeypatch):
     Q = _queries(X, 5, seed=13)
     if kind == "knn":
         ex.knn_query_batch(Q, 7)
+        (cert,) = certified
     else:
         ex.range_query_batch(Q, np.array([float(np.quantile(
             dist_one_to_many(q, X, "l2"), 0.05)) for q in Q]))
     (mask,) = masks
+    if kind == "range":
+        cert = mask
+    assert not np.any(mask & ~cert)       # the band only takes rows away
     p = ex.last_profile
     B = len(Q)
     K, n_max, _ = snap.rids.shape
-    nz = [np.nonzero(mask[b])[0] for b in range(B)]
+    nz = [np.nonzero(cert[b])[0] for b in range(B)]
     assert p.candidates_per_query == float(
         np.mean([len(i) for i in nz]))
     assert p.clusters_per_query == float(
         np.mean([len(np.unique(i // n_max)) for i in nz]))
+    assert p.refined_per_query == float(mask.sum(axis=1).mean())
+    if kind == "knn":                     # the band engaged
+        assert p.refined_per_query < p.candidates_per_query
     # the rank-health sample: the deterministic stride over the in-ring
-    # slots of the mask's union, the last rows the batch read
+    # slots of the packed mask's union, the last rows the batch read
     sample = np.nonzero(mask.any(axis=0) &
                         np.asarray(snap.in_ring).reshape(-1))[0]
     n = ex._HEALTH_SAMPLE

@@ -486,12 +486,14 @@ def test_frontend_host_syncs_same_every_batch(setup, monkeypatch):
 def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
     """A routed batch's ``d2h_bytes`` is exactly what its copies hold:
     kNN — (B, K, m) f32 seed distances, (B, K) bool routing, the
-    certified mask packed into (B, W) uint32 words and the int32 round
-    count; range — routing and the packed hits; with compaction on, the
+    banded candidate mask packed into (B, W) uint32 words, the int32
+    round count and the certified set's (B,) int32 row and cluster
+    counts; range — routing and the packed hits; with compaction on, the
     (B, n_slots) bool host candidate mask it reads comes first, and the
     (B, bucket) uint8 ball replaces the packed hits unless the union is
-    too large to gather.  No sync is added for the packing: kNN syncs 3
-    times (seed, routing, words and round count), range twice (routing,
+    too large to gather.  No sync is added for the packing or the
+    counts: kNN syncs 3 times (seed, routing, words with the round count
+    and the counts), range twice (routing,
     hits) or, compacted, three times (routing, mask, ball); every batch
     whose mask is computed on the device counts
     ``executor.packed_batches``, every other ``executor.dense_batches``."""
@@ -515,7 +517,7 @@ def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
     want = B * K
     lc = ex.last_compact
     if kind == "knn":
-        want += B * K * m * 4 + packed + 4
+        want += B * K * m * 4 + packed + 4 + 2 * 4 * B
     elif compact == "on":
         want += B * s.n_slots + (packed if lc is None else B * lc["bucket"])
     else:
@@ -536,7 +538,8 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     copies — kNN: the (n, K, m) f32 seed distances and (n, K) bool
     routing once, at the n rows the batch is planned at, then each
     sub-batch's packed (cap, W) uint32 mask, padded to the replica's
-    cap rows, and int32 round count; range: the routing once, then
+    cap rows, int32 round count and (cap,) int32 certified row and
+    cluster counts; range: the routing once, then
     each sub-batch's packed hits."""
     from repro.serving import ServingFrontend
     monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
@@ -559,7 +562,7 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     n, cap = fe._router_obj.shapes(len(Q))
     packed = 4 * G * cap * _pack_width(snap.n_slots)
     if kind == "knn":
-        want = n * K * m * 4 + n * K + packed + 4 * G
+        want = n * K * m * 4 + n * K + packed + 4 * G + 2 * 4 * G * cap
         syncs = 2 + G
     else:
         want = n * K + packed

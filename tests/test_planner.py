@@ -218,11 +218,18 @@ def test_knn_rounds_driver_matches_loop_driver(executors, setup,
                                                monkeypatch):
     """The interpret-mode vectorized-round driver (the PR-5 q/s
     regression fix) executes the same certified schedule as the
-    compiled ``lax.while_loop`` — results bit-identical, driver
-    surfaced in ``last_knn``."""
+    compiled ``lax.while_loop`` — results bit-identical, the banded
+    sets refinement scans identical too, driver surfaced in
+    ``last_knn``."""
     X = setup[0]
     ex = executors["resident"]
     Q = _queries(X, 5, seed=17)
+    refine, sets = ex._refine_topk, []
+
+    def keep(Q64, final, k_eff):
+        sets.append(final)
+        return refine(Q64, final, k_eff)
+    monkeypatch.setattr(ex, "_refine_topk", keep)
     for k in (4, 23):
         monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
         ids_l, ds_l = ex.knn_query_batch(Q, k)
@@ -233,3 +240,6 @@ def test_knn_rounds_driver_matches_loop_driver(executors, setup,
         assert ex.last_knn["host_syncs"] >= ex.last_knn["rounds"]
         assert np.array_equal(ids_l, ids_r)
         assert np.array_equal(ds_l, ds_r)
+        loop_set, rounds_set = sets[-2:]
+        assert np.array_equal(loop_set.offsets, rounds_set.offsets)
+        assert np.array_equal(loop_set.slots, rounds_set.slots)

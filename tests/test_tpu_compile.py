@@ -95,3 +95,17 @@ def test_pack_mask_compiles_without_relayout(one_chip, slots):
     assert compiled.out_info.shape == (B, _pack_width(slots))
     assert " copy(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < B * slots // 8
+
+
+def test_knn_band_compiles(one_chip):
+    """The kNN loop's k-th-distance band (``executor._knn_band``) over
+    the smoke run's slot plane: its upward f32 rounding (``nextafter``)
+    lowers for the chip, and the band is one pass over the distances,
+    with no scratch buffer."""
+    from repro.core.executor import _knn_band
+    d2 = jax.ShapeDtypeStruct((B, SLOTS), jnp.float32, sharding=one_chip)
+    kth = jax.ShapeDtypeStruct((B,), jnp.float32, sharding=one_chip)
+    eps = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = _knn_band.lower(d2, kth, eps).compile()
+    assert compiled.out_info.shape == (B, SLOTS)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
