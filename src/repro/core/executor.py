@@ -67,7 +67,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import env
 from ..kernels import ops
 from ..kernels.dispatch import compact_enabled
 from ..obs import registry as _obs
@@ -265,28 +264,6 @@ def _knn_loop_single(qf, d2, kth0, r0, eps, *arrays, n_rings, k_eff,
         kth_select=lambda dm: _smallest_k(dm, k_eff)[:, -1])
 
 
-@jax.jit
-def _knn_round_masks(d2, cand, rf, eps):
-    """The fused wide part of one host-rounds round: certified ball
-    mask, candidate count, and the masked distance matrix in a single
-    launch.
-
-    The eager spelling streams the (B, n_slots) matrix once per op —
-    ball, candb, cnt, dm are four separate passes; fused, XLA reads
-    ``d2``/``cand`` once and writes ``candb``/``dm`` once.  The k-th
-    selection stays *outside*: XLA-CPU's jitted TopK lowering is an
-    order of magnitude slower than its eager dispatch (the same cliff
-    that routes REPRO_KNN_DRIVER=auto to this driver), so the round
-    fuses everything except TopK.  Same jnp graph as the eager version
-    (elementwise math, exact bool-sum reduction) so the outputs are
-    bit-identical — a bytes-moved optimization, not a math change."""
-    ball = d2 <= ((rf * (1.0 + _R_REL) + _BALL_ABS + eps) ** 2)[:, None]
-    candb = cand & ball
-    cnt = jnp.sum(candb, axis=1)
-    dm = jnp.where(candb, d2, jnp.inf)
-    return candb, cnt, dm
-
-
 # ---------------------------------------------------------------------------
 # candidate sets to the host: a (B, P) bool mask leaves the device as
 # (B, W) uint32 words and becomes per-query slot lists on the host
@@ -411,26 +388,6 @@ class CandidateSets:
 # ---------------------------------------------------------------------------
 # execution backends (both consume the same CandidatePlan)
 # ---------------------------------------------------------------------------
-def _knn_driver(ex) -> str:
-    """Which resident kNN driver executes the schedule, resolved per
-    call so ``REPRO_KNN_DRIVER`` monkeypatching works on long-lived
-    executors.  ``loop`` is the compiled ``lax.while_loop``; ``rounds``
-    is the host-driven vectorized-round driver.  ``auto`` (default)
-    picks ``rounds`` on single-device XLA-CPU — the while_loop/TopK
-    cliff is a property of XLA's CPU lowerings (notably ``top_k``, ~40×
-    its eager dispatch; the PR-5 ~433 → ~181 q/s regression), not of
-    interpret mode, so the compiled xla lane takes the same exit — and
-    ``loop`` everywhere else: real accelerators keep O(1) host syncs,
-    and the sharded loop's per-round collectives have no eager
-    equivalent."""
-    mode = env.get("REPRO_KNN_DRIVER")
-    if mode in ("loop", "rounds"):
-        return mode
-    if jax.default_backend() == "cpu" and getattr(ex, "n_shards", 1) <= 1:
-        return "rounds"
-    return "loop"
-
-
 class _ResidentBackend:
     """In-memory execution: kernels over the snapshot's device rows."""
 
@@ -499,8 +456,6 @@ class _ResidentBackend:
         clusters) per query): the sets refinement scans, and the
         pruning power of the LIMS-certified sets they were cut from."""
         ex = self.ex
-        if _knn_driver(ex) == "rounds":
-            return self._knn_host_rounds(plan)
         ex.last_driver = "loop"
         r0 = jnp.asarray(plan.radii, jnp.float32)
         final, rounds, cnt, clusters = ex._knn_device_loop(
@@ -509,61 +464,6 @@ class _ResidentBackend:
             (_pack_mask(final), rounds, cnt, clusters))
         return CandidateSets.from_packed(words), int(rounds), \
             (cnt, clusters)
-
-    def _knn_host_rounds(self, plan: CandidatePlan):
-        """The same certified schedule as ``_knn_rounds``, driven from
-        the host with eager per-round kernel dispatches: identical seed
-        skip-ahead, identical guard-band certification, identical exact
-        fallback — only the loop control moves to Python, trading O(1)
-        host syncs for XLA-CPU's fast eager lowerings.  The certified
-        set is a superset of the closed k-th ball at whatever schedule
-        radius certifies, so refinement returns bit-identical results
-        whichever driver ran (pinned by tests); the k-th-distance band
-        is the loop's own ``_knn_band``, so the sets are identical too."""
-        ex = self.ex
-        s = ex.snap
-        qf = plan.qf
-        k_eff = plan.k
-        cost = plan.cost
-        d2, eps = ex._filter_dists(qf)
-        kth0 = jnp.sqrt(jnp.maximum(
-            -jax.lax.top_k(-d2, k_eff)[0][:, -1], 0.0))
-        r0 = jnp.asarray(plan.radii, jnp.float32)
-        seed = kth0 * (1.0 + _SEED_REL) + _BALL_ABS
-        t0 = jnp.ceil(jnp.log2(jnp.maximum(seed, 1e-30) / r0))
-        r = cost.to_host(r0 * jnp.exp2(jnp.maximum(t0, 0.0)))
-        B = plan.B
-        done = np.zeros(B, bool)
-        final = np.zeros((B, s.n_slots), bool)
-        rounds = 0
-        for t in range(plan.max_rounds):
-            rounds = t + 1
-            rf = jnp.asarray(r, jnp.float32)
-            cand = ex._candidate_mask(qf, rf)
-            # same ±eps adjustments as _knn_rounds: widen the ball so
-            # the lp plane can't cut a true candidate, tighten the
-            # certification by the margin the filtered k-th may be off
-            # (fused wide passes; TopK stays eager — see _knn_round_masks)
-            candb, cnt, dm = _knn_round_masks(d2, cand, rf,
-                                              jnp.float32(eps))
-            kth = jnp.sqrt(jnp.maximum(
-                -jax.lax.top_k(-dm, k_eff)[0][:, -1], 0.0))
-            ok = cost.to_host((cnt >= k_eff) &
-                              (kth <= rf * (1.0 - _R_REL) - _BALL_ABS - eps))
-            newly = ok & ~done
-            if newly.any():
-                final[newly] = cost.to_host(candb)[newly]
-                done |= newly
-            if done.all():
-                break
-            r = np.where(done, r, r * 2.0)
-        else:
-            final[~done] = s.valid_np[None]
-        ex.last_driver = "rounds"
-        cnt = final.sum(axis=1)
-        clusters = final.reshape((B,) + s.valid.shape).any(-1).sum(-1)
-        final &= cost.to_host(_knn_band(d2, kth0, jnp.float32(eps)))
-        return CandidateSets.from_mask(final), rounds, (cnt, clusters)
 
 
 class _PagedBackend:

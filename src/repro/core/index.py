@@ -31,10 +31,8 @@ from .rankmodel import PolyRankModel, SearchStats, binary_search, exponential_se
 
 # retrain ``backend="auto"`` crossover: below this many member rows the
 # host numpy rebuild beats the device builder's per-launch dispatch
-# overhead (BENCH_build.json pins host ahead at ~125 and ~500
-# rows/cluster on CPU; the compiled-lane crossover is re-measured by
-# ``benchmarks/bench_build.py`` and recorded next to this constant's
-# routing decisions)
+# overhead.  The value was set from CPU timings; it has not been
+# measured on the chip.
 RETRAIN_AUTO_ROWS = 4096
 
 
@@ -498,8 +496,8 @@ class LIMSIndex:
         exact f64 either way, so results stay exact (DESIGN.md §6).
         ``"auto"`` routes on the member row count: the host numpy
         rebuild wins below ``RETRAIN_AUTO_ROWS`` rows, where device
-        dispatch overhead dominates (the crossover is measured in
-        ``benchmarks/bench_build.py`` → ``BENCH_build.json``); custom /
+        dispatch overhead dominates (a crossover set from CPU timings,
+        not measured on the chip); custom /
         non-vector metrics and the interpret kernel lane always take
         the host path (the device builder can't serve them / only costs
         there).  The chosen backend lands in ``last_retrain_backend``.

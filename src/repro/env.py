@@ -10,9 +10,8 @@ comparison.
 
 Conventions:
 
-* The empty string is always accepted and means "use the default" —
-  benchmark harnesses explicitly blank knobs between configs
-  (``env["REPRO_STORAGE"] = ""``) and that must stay valid.
+* The empty string is always accepted and means "use the default" — a
+  harness may blank a knob (``REPRO_STORAGE=``) to restore it.
 * Values are matched case-insensitively after stripping whitespace.
 * Free-form knobs (paths) declare ``values=None`` and are returned raw.
 """
@@ -36,10 +35,6 @@ _KNOBS = (
          "Kernel execution lane: auto (interpret on CPU, compiled pallas "
          "on TPU/GPU), on (force pallas interpret), off (force the "
          "compiled lane: pallas on TPU/GPU, jitted-XLA on CPU)."),
-    Knob("REPRO_PALLAS_INTERPRET",
-         ("", "auto", "0", "1", "false", "true"), "",
-         "Legacy alias for REPRO_INTERPRET (1/true -> on, 0/false -> "
-         "off). Ignored when REPRO_INTERPRET is set."),
     Knob("REPRO_AUTOTUNE",
          ("", "off", "on", "force"), "on",
          "Kernel tile autotuning: off (static heuristics), on (consult "
@@ -71,14 +66,6 @@ _KNOBS = (
          "radius so no true result can be cut (exact f32/f64 refinement "
          "keeps final results bitwise identical). off/f32 (default) "
          "disables the extra plane."),
-    Knob("REPRO_KNN_DRIVER",
-         ("", "auto", "loop", "rounds"), "auto",
-         "kNN driver: loop (device lax.while_loop), rounds (host-stepped "
-         "vectorized rounds), auto (rounds on single-shard XLA-CPU, "
-         "loop elsewhere)."),
-    Knob("REPRO_REAL_IO",
-         ("", "0", "1"), "",
-         "Benchmarks: drop the OS page cache before cold paged passes."),
     Knob("REPRO_OBS",
          ("", "off", "on"), "on",
          "Observability (repro.obs; DESIGN.md §11): off (zero-cost "
@@ -148,11 +135,11 @@ _CACHE_DIR = os.path.join(
 def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Entry points (``chip_smoke.py``, ``benchmarks/run.py``, the
-    examples) call this once at start-up; importing the package never
-    does.  A set ``JAX_COMPILATION_CACHE_DIR`` is already read by JAX
-    itself and wins — nothing is set in code then.  Otherwise the cache
-    lives in ``.jax_cache`` at the root of the checkout.
+    Entry points (``chip_smoke.py``, ``bench/run.py``, the examples)
+    call this once at start-up; importing the package never does.  A
+    set ``JAX_COMPILATION_CACHE_DIR`` is already read by JAX itself and
+    wins — nothing is set in code then.  Otherwise the cache lives in
+    ``.jax_cache`` at the root of the checkout.
     """
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:

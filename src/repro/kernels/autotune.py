@@ -418,8 +418,8 @@ def _write_user_cache(key: str, ent: dict) -> None:
 
 # ------------------------------------------------------------------ warm
 
-# the pipeline's standard shape buckets: (kernel, metric, dims) — the
-# bench_kernels shapes plus the serving/roofline refinement bucket
+# the pipeline's standard shape buckets: (kernel, metric, dims) — a
+# 256 × 65k × 32 kernel shape plus the serving refinement bucket
 # (batch 64 queries × ~100k padded slots × d=8, the shape the resident
 # executor's range/knn filters actually launch at)
 _WARM_FULL = (

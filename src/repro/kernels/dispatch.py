@@ -15,9 +15,7 @@ takes (:func:`kernel_mode`):
 
 ``REPRO_INTERPRET=auto|on|off`` selects: ``auto`` interprets on CPU and
 compiles pallas on TPU/GPU; ``on`` forces interpret everywhere; ``off``
-forces the compiled lane (pallas where it compiles, xla on CPU).  The
-legacy ``REPRO_PALLAS_INTERPRET=1|0`` spelling maps to on/off when
-``REPRO_INTERPRET`` is unset.
+forces the compiled lane (pallas where it compiles, xla on CPU).
 
 Pallas kernels still take ``interpret: bool | None = None`` and resolve
 ``None`` through :func:`resolve_interpret`; the xla-vs-pallas choice is
@@ -35,12 +33,6 @@ _PALLAS_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 def kernel_mode() -> str:
     """The executable lane for this process: interpret | xla | pallas."""
     v = env.get("REPRO_INTERPRET")
-    if v == "auto":
-        legacy = env.get("REPRO_PALLAS_INTERPRET")
-        if legacy in ("1", "true"):
-            v = "on"
-        elif legacy in ("0", "false"):
-            v = "off"
     if v == "on":
         return "interpret"
     pallas_compiles = jax.default_backend() in _PALLAS_BACKENDS

@@ -67,7 +67,7 @@ class QueryProfile:
     batch: int                   # queries in the batch
     k: int | None                # kNN k (None for range)
     backend: str                 # "resident" | "paged"
-    driver: str | None           # kNN driver (loop|rounds|paged); None range
+    driver: str | None           # kNN driver (loop|paged); None range
     storage: str                 # "resident" | "paged"
     n_shards: int
     rounds: int                  # growing-radius rounds (1 for range)

@@ -2,8 +2,8 @@
 
 Published per-chip peaks live in one table, :data:`DEVICE_PEAKS`, keyed
 by ``jax.Device.device_kind``; :func:`machine_profile` returns the
-``{"name", "peak_flops", "mem_bw"}`` ceiling the pipeline roofline
-report divides by.  An accelerator whose kind is not in the table is an
+``{"name", "peak_flops", "mem_bw"}`` ceiling a roofline divides by.
+An accelerator whose kind is not in the table is an
 error, never a default.  On CPU the peaks are *calibrated* — a jitted
 f32 GEMM for peak FLOP/s and a large streaming elementwise op for
 memory bandwidth, best-of several runs, cached per process — because

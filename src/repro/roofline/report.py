@@ -1,13 +1,5 @@
-"""Render roofline reports.
-
-Two modes:
-
-* ``python -m repro.roofline.report --pipeline [--quick] [--json PATH]``
-  — measure the real pdist→rankeval→range_filter query pipeline on this
-  machine (compiled lane, calibrated ceiling; see ``pipeline.py``) and
-  print the per-stage utilization table.
-* ``python -m repro.roofline.report [DIR]`` — the original dry-run
-  tables from ``results/dryrun`` JSON records.
+"""Render roofline reports: ``python -m repro.roofline.report [DIR]``
+prints the dry-run tables from ``results/dryrun`` JSON records.
 """
 from __future__ import annotations
 
@@ -71,16 +63,6 @@ def collective_summary(recs: list[dict]) -> str:
 
 
 def main() -> None:
-    if "--pipeline" in sys.argv:
-        from .pipeline import pipeline_report, render
-        rep = pipeline_report(quick="--quick" in sys.argv)
-        print(render(rep))
-        if "--json" in sys.argv:
-            path = sys.argv[sys.argv.index("--json") + 1]
-            with open(path, "w") as f:
-                json.dump(rep, f, indent=1)
-            print(f"# wrote {path}")
-        return
     d = sys.argv[1] if len(sys.argv) > 1 else "results/dryrun"
     recs = load(d)
     print("## Roofline — single pod (16×16 = 256 chips)\n")

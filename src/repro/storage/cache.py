@@ -21,8 +21,7 @@ insert — when every resident page is pinned the cache briefly overflows
 capacity (bounded by one batch's working set) rather than corrupt a
 planned fetch.  ``unpin`` restores plain LRU: the page keeps the
 recency position its accesses earned and becomes evictable again.
-``REPRO_CACHE_PIN=off`` disables plan pinning process-wide (the bench's
-blind-LRU baseline).
+``REPRO_CACHE_PIN=off`` disables plan pinning process-wide (blind LRU).
 
 ``CacheStats`` carries two families of counters:
 
@@ -30,8 +29,7 @@ blind-LRU baseline).
     evictions / rows gathered — the buffer-pool story;
   * per-query serving metrics recorded by the executor: unique pages
     touched and candidate rows refined per query — the paper's headline
-    cost model (page accesses per query), surfaced in
-    ``BENCH_serving.json`` alongside q/s.
+    cost model (page accesses per query).
 """
 from __future__ import annotations
 
@@ -47,8 +45,8 @@ DEFAULT_CACHE_PAGES = 4096
 
 def cache_pin_mode() -> bool:
     """Whether planned batches pin their scheduled pages (default on).
-    ``REPRO_CACHE_PIN=off`` reverts to blind LRU — the bench baseline
-    (validated by ``repro.env``)."""
+    ``REPRO_CACHE_PIN=off`` reverts to blind LRU (validated by
+    ``repro.env``)."""
     return env.get("REPRO_CACHE_PIN") not in ("off", "0", "no")
 
 

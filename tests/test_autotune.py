@@ -13,7 +13,7 @@ Pins the PR's correctness claims:
   and replaced by a valid entry under ``REPRO_AUTOTUNE=force``
   (round-trip through the JSON file);
 * the env-knob registry rejects unknown knobs and invalid values with
-  actionable errors.
+  actionable errors, and declares exactly the knobs the package reads.
 """
 import functools
 import json
@@ -82,15 +82,38 @@ def test_env_empty_and_unset_mean_default(monkeypatch):
     assert env.get("REPRO_AUTOTUNE") == "on"
     monkeypatch.setenv("REPRO_AUTOTUNE", "")
     assert env.get("REPRO_AUTOTUNE") == "on"
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "ROUNDS")   # case-insensitive
-    assert env.get("REPRO_KNN_DRIVER") == "rounds"
+    monkeypatch.setenv("REPRO_PREFETCH", "ASYNC")      # case-insensitive
+    assert env.get("REPRO_PREFETCH") == "async"
 
 
 def test_env_describe_covers_all_knobs():
     text = env.describe()
     for name in ("REPRO_INTERPRET", "REPRO_AUTOTUNE", "REPRO_TUNE_CACHE",
-                 "REPRO_KNN_DRIVER", "REPRO_STORAGE"):
+                 "REPRO_PREFETCH", "REPRO_STORAGE"):
         assert name in text
+
+
+def test_env_every_knob_declared_is_read_and_every_read_declared():
+    """Each knob in ``env._KNOBS`` is named by some module of the
+    package besides the registry, and every ``REPRO_*`` name a module
+    there spells out is declared: a knob nothing reads, or a read of an
+    undeclared one, fails here and not at serve time."""
+    import ast
+    import pathlib
+    import re
+
+    registry = pathlib.Path(env.__file__)
+    named = set()
+    for path in registry.parent.rglob("*.py"):
+        if path == registry:
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+                    and re.fullmatch(r"REPRO_[A-Z0-9_]+", node.value)):
+                named.add(node.value)
+    declared = {k.name for k in env._KNOBS}
+    assert not declared - named, f"never read: {sorted(declared - named)}"
+    assert not named - declared, f"undeclared: {sorted(named - declared)}"
 
 
 # ------------------------------------------------------- tile properties

@@ -135,23 +135,21 @@ def _queries(X, n_q, seed):
 
 
 @pytest.mark.parametrize("name", ["resident", "sharded"])
-@pytest.mark.parametrize("driver", ["loop", "rounds"])
-def test_knn_matches_golden_and_host_with_ties(name, driver, monkeypatch):
+@pytest.mark.parametrize("k", [1, 6, 25])
+def test_knn_matches_golden_and_host_with_ties(name, k):
     """kNN through the executor equals the golden driver bit for bit —
     ids in tie order too — and the host index in distances and ids."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", driver)
     X, ix, snap, exs = _env()
     ex = exs[name]
     Q = _queries(X, 6, seed=4)
-    for k in (1, 6, 25):
-        ids, ds = ex.knn_query_batch(Q, k)
-        g_ids, g_ds = golden.knn_resident(exs["resident"], Q, k)
-        assert np.array_equal(ids, g_ids) and np.array_equal(ds, g_ds)
-        for b, q in enumerate(Q):
-            h_ids, h_ds, _ = ix.knn_query(q, k)
-            assert np.array_equal(ds[b], h_ds)
-            tie = ds[b] == ds[b, -1]         # the k-th ball's edge may tie
-            assert set(ids[b][~tie]) == set(h_ids[~tie])
+    ids, ds = ex.knn_query_batch(Q, k)
+    g_ids, g_ds = golden.knn_resident(exs["resident"], Q, k)
+    assert np.array_equal(ids, g_ids) and np.array_equal(ds, g_ds)
+    for b, q in enumerate(Q):
+        h_ids, h_ds, _ = ix.knn_query(q, k)
+        assert np.array_equal(ds[b], h_ds)
+        tie = ds[b] == ds[b, -1]             # the k-th ball's edge may tie
+        assert set(ids[b][~tie]) == set(h_ids[~tie])
     d0 = dist_one_to_many(Q[0], X, "l2")
     assert np.sum(d0 == 0.0) == 2            # the tie is there
 
@@ -187,7 +185,6 @@ def test_profile_reads_what_the_dense_mask_gives(kind, monkeypatch):
     refinement read gives (kNN: the certified set cut to the band); and
     ``rank_err_ratio`` is computed over the same sampled slots the
     packed mask's union gives."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", "off")
     X, ix, snap, exs = _env()
     obs.configure("on")

@@ -245,12 +245,10 @@ def test_padded_subbatches_bit_identical(setup, B, kind):
     assert all(pad == 8 - n for _, _, pad, n in seen)
 
 
-@pytest.mark.parametrize("driver", ["rounds", "loop"])
-def test_padding_never_adds_knn_rounds(setup, monkeypatch, driver):
+def test_padding_never_adds_knn_rounds(setup):
     """A padding row copies a real query of its sub-batch, so it
     certifies when that query does: each sub-batch takes as many rounds
     as its slowest real query alone."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", driver)
     X, ix, snap, path = setup
     router, seen = _padded_router(snap)
     Q = _queries(X, 11, seed=41, scale=0.05)
@@ -266,7 +264,7 @@ def test_padding_never_adds_knn_rounds(setup, monkeypatch, driver):
     for rep in router.replicas.members:
         idx = np.nonzero(pick == rep.rid)[0]
         if len(idx):
-            assert rep.ex.last_knn["driver"] == driver
+            assert rep.ex.last_knn["driver"] == "loop"
             assert rep.ex.last_knn["rounds"] == max(solo[i] for i in idx)
 
 

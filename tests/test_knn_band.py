@@ -9,9 +9,9 @@ holds for the f32 k-th distance, never narrower; every refined set lies
 inside the band and holds the whole closed f64 k-th ball; and answers
 stay bit-identical to the golden driver and equal to a linear scan and
 the host index — on a corpus whose rows come in threes, so several rows
-tie exactly at the k-th distance — for k = 1, 10 and near ``live``, on
-certified and fallback rows, through the compiled loop, the host rounds
-driver and the sharded loop.
+tie exactly at the k-th distance — for k = 1, 10, either side of
+``_smallest_k``'s switch to ``top_k`` and near ``live``, on certified
+and fallback rows, through the compiled loop and the sharded loop.
 """
 import functools
 
@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from repro.baselines.linear_scan import LinearScan
 from repro.core import LIMSIndex, MetricSpace
-from repro.core import executor as executor_mod
 from repro.core.executor import QueryExecutor, ShardedExecutor, _knn_band
 from repro.core.metrics import dist_one_to_many
 from repro.core.planner import _BALL_ABS, _R_REL
@@ -80,41 +79,38 @@ def _ties_at_kth(X, q, k):
     return int(np.sum(d == d[k - 1]))
 
 
-# (executor, driver, k, max_rounds, seed): k None is near ``live``;
-# max_rounds 0 runs no round, so every row takes the exact fallback;
-# "small" shrinks the plan's seed radii and the skip-ahead below the
-# k-th distance, so the one round fails on the host rounds driver (the
-# only driver whose skip-ahead is not fixed when the loop compiles)
+# (executor, k, max_rounds, seed): k None is near ``live``; max_rounds
+# 0 runs no round, so every row takes the exact fallback, and 1 allows
+# only the round the skip-ahead starts at; k 64 and 65 sit either side
+# of ``_smallest_k``'s switch from argmin sweeps to ``top_k``; "small"
+# shrinks the plan's seed radii a thousandfold, which the skip-ahead
+# from ``kth0`` must make up within that one round
 CASES = [
-    ("resident", "loop", 1, 64, None), ("resident", "loop", 10, 64, None),
-    ("resident", "loop", None, 64, None), ("resident", "loop", 10, 0, None),
-    ("resident", "rounds", 1, 64, None),
-    ("resident", "rounds", 10, 64, None),
-    ("resident", "rounds", None, 64, None),
-    ("resident", "rounds", 10, 0, None),
-    ("resident", "rounds", 10, 1, "small"),
-    ("sharded", "loop", 1, 64, None), ("sharded", "loop", 10, 64, None),
-    ("sharded", "loop", None, 64, None), ("sharded", "loop", 10, 0, None),
+    ("resident", 1, 64, None), ("resident", 10, 64, None),
+    ("resident", None, 64, None), ("resident", 10, 0, None),
+    ("resident", 10, 1, None), ("resident", 64, 64, None),
+    ("resident", 65, 64, None), ("resident", 10, 1, "small"),
+    ("sharded", 1, 64, None), ("sharded", 10, 64, None),
+    ("sharded", None, 64, None), ("sharded", 10, 0, None),
+    ("sharded", 10, 1, None),
 ]
 
 
-@pytest.mark.parametrize("name,driver,k,max_rounds,seed", CASES)
+@pytest.mark.parametrize("name,k,max_rounds,seed", CASES)
 def test_refined_sets_are_the_band_and_answers_unchanged(
-        name, driver, k, max_rounds, seed, monkeypatch):
+        name, k, max_rounds, seed, monkeypatch):
     """Every refined set lies inside the band (true distance within
     ~2·``_BALL_ABS`` of the k-th) and holds every valid slot whose f64
     distance is at most the k-th; the certified counts the profile
     reads are the LIMS set's (``live`` on a fallback row); and the
     answers equal the golden driver's bit for bit, and a linear scan's
     and the host index's distances, ids wherever no tie decides."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", driver)
     X, ix, scan, exs = _env()
     ex = exs[name]
     s = ex.snap
     k = s.live - 3 if k is None else k
     Q = _queries(X)
     if seed == "small":
-        monkeypatch.setattr(executor_mod, "_SEED_REL", -0.9)
         plan_knn = ex.planner.plan_knn
 
         def small_plan(*a):
@@ -161,8 +157,8 @@ def test_refined_sets_are_the_band_and_answers_unchanged(
         assert len(mine) <= cnt[b]
     if max_rounds == 0:
         assert got["rounds"] == 0 and (cnt == s.live).all()
-    if seed == "small":
-        assert got["rounds"] == 1 and (cnt == s.live).any()
+    if max_rounds == 1:                        # certified, no fallback
+        assert got["rounds"] == 1 and (cnt < s.live).all()
     if k < 64:                                  # the band engaged
         assert refined.counts.sum() < cnt.sum()
     K = s.rids.shape[0]

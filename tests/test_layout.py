@@ -13,7 +13,7 @@ in results:
   * **certified reduced-precision filter plane** — with
     ``REPRO_ROWS_DTYPE=bf16|f16`` the ε-widened filters keep every true
     result (property-tested) and final query results stay bitwise
-    identical to the f32 baseline across both kNN drivers and the
+    identical to the f32 baseline through the compiled kNN loop and the
     sharded executor (the 4-fake-device CI leg runs the real
     ``shard_map`` path through this file).
 """
@@ -201,23 +201,21 @@ def test_pallas_point_major_grid_matches_reference():
 
 # -------------------------------------- reduced-precision filter plane
 @pytest.mark.parametrize("dtype", ["bf16", "f16"])
-@pytest.mark.parametrize("driver", ["rounds", "loop"])
-def test_lp_plane_results_bitwise_identical(monkeypatch, dtype, driver):
+def test_lp_plane_results_bitwise_identical(monkeypatch, dtype):
     """The ε-certified lp filter plane changes first-pass byte traffic
     only: range and kNN results are bitwise identical to the f32
-    baseline under both kNN drivers."""
+    baseline through the compiled kNN loop."""
     X, ix = _env()
     monkeypatch.delenv("REPRO_ROWS_DTYPE", raising=False)
     base_snap = LIMSSnapshot.build(ix)
     base = _run_queries(QueryExecutor(base_snap), X)
     monkeypatch.setenv("REPRO_ROWS_DTYPE", dtype)
-    monkeypatch.setenv("REPRO_KNN_DRIVER", driver)
     snap = LIMSSnapshot.build(ix)
     assert snap.rows_lp is not None and snap.lp_eps > 0.0
     ex = QueryExecutor(snap)
     got = _run_queries(ex, X)
     _assert_same(got, base)
-    assert ex.last_knn["driver"] == driver
+    assert ex.last_knn["driver"] == "loop"
 
 
 def test_lp_plane_sharded_and_compact_identical(monkeypatch):

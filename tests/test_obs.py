@@ -462,11 +462,10 @@ def test_profiler_capture_holds_program_spans(setup, tmp_path):
                    for e in ev)
 
 
-def test_frontend_host_syncs_same_every_batch(setup, monkeypatch):
+def test_frontend_host_syncs_same_every_batch(setup):
     """Regression: through the frontend, a batch's host syncs are its
     own — the same count on every one of consecutive equal batches, not
     a per-thread total that grows batch after batch."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     X, ix, snap, path, Q, rs = setup
     obs.configure("on")
     obs.clear_profiles()
@@ -497,7 +496,6 @@ def test_d2h_bytes_match_shapes(setup, monkeypatch, kind, compact):
     hits) or, compacted, three times (routing, mask, ball); every batch
     whose mask is computed on the device counts
     ``executor.packed_batches``, every other ``executor.dense_batches``."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", compact)
     X, ix, snap, path, Q, rs = setup
     obs.configure("on")
@@ -542,7 +540,6 @@ def test_split_batch_charges_each_copy_once(setup, monkeypatch, kind):
     cluster counts; range: the routing once, then
     each sub-batch's packed hits."""
     from repro.serving import ServingFrontend
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
     monkeypatch.setenv("REPRO_COMPACT", "off")
     X, ix, snap, path, Q, rs = setup
     obs.configure("on")

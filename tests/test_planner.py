@@ -183,18 +183,12 @@ def test_unified_path_matches_golden_drivers(executors, setup):
 
 
 # --------------------------------------------------- host-sync counter
-def test_knn_host_syncs_constant_in_compiled_path(executors, setup,
-                                                  monkeypatch):
+def test_knn_host_syncs_constant_in_compiled_path(executors, setup):
     """Acceptance criterion: the device-resident kNN *loop* costs O(1)
     host syncs per batch — one for the plan's seed radii, one for the
     loop's certified masks — independent of workload (k, batch size,
     rounds).  The sharded executor must hold the same bound: its loop
-    keeps every per-round reduction a collective.  Pinned to the
-    compiled driver: ``REPRO_KNN_DRIVER=auto`` picks the host-driven
-    vectorized-round driver on single-device XLA-CPU interpret (per
-    round, eager dispatch beats the jitted loop's slow lowerings —
-    see the driver test below), which syncs per round by design."""
-    monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
+    keeps every per-round reduction a collective."""
     X = setup[0]
     for name in ("resident", "sharded"):
         ex = executors[name]
@@ -213,33 +207,3 @@ def test_knn_host_syncs_constant_in_compiled_path(executors, setup,
     assert pag.last_knn["backend"] == "paged"
     assert pag.last_knn["rounds"] >= 1
 
-
-def test_knn_rounds_driver_matches_loop_driver(executors, setup,
-                                               monkeypatch):
-    """The interpret-mode vectorized-round driver (the PR-5 q/s
-    regression fix) executes the same certified schedule as the
-    compiled ``lax.while_loop`` — results bit-identical, the banded
-    sets refinement scans identical too, driver surfaced in
-    ``last_knn``."""
-    X = setup[0]
-    ex = executors["resident"]
-    Q = _queries(X, 5, seed=17)
-    refine, sets = ex._refine_topk, []
-
-    def keep(Q64, final, k_eff):
-        sets.append(final)
-        return refine(Q64, final, k_eff)
-    monkeypatch.setattr(ex, "_refine_topk", keep)
-    for k in (4, 23):
-        monkeypatch.setenv("REPRO_KNN_DRIVER", "loop")
-        ids_l, ds_l = ex.knn_query_batch(Q, k)
-        assert ex.last_knn["driver"] == "loop"
-        monkeypatch.setenv("REPRO_KNN_DRIVER", "rounds")
-        ids_r, ds_r = ex.knn_query_batch(Q, k)
-        assert ex.last_knn["driver"] == "rounds"
-        assert ex.last_knn["host_syncs"] >= ex.last_knn["rounds"]
-        assert np.array_equal(ids_l, ids_r)
-        assert np.array_equal(ds_l, ds_r)
-        loop_set, rounds_set = sets[-2:]
-        assert np.array_equal(loop_set.offsets, rounds_set.offsets)
-        assert np.array_equal(loop_set.slots, rounds_set.slots)

@@ -577,7 +577,7 @@ def test_plan_pins_released_after_batch(setup, monkeypatch):
 
 
 def test_pin_mode_off_is_blind_lru(setup, monkeypatch):
-    """``REPRO_CACHE_PIN=off`` (the bench's baseline) takes no holds at
+    """``REPRO_CACHE_PIN=off`` (blind LRU) takes no holds at
     all — and results are unchanged either way."""
     X, ix, snap, path = setup
     monkeypatch.setenv("REPRO_CACHE_PIN", "off")
